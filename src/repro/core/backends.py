@@ -1,10 +1,11 @@
 """Measurement oracles.
 
-The container is CPU-only, so two backends stand in for the paper's CUDA
-events:
+Two oracles stand in for the paper's CUDA events:
 
-* ``cpu_wallclock`` — host timing of the jit-compiled entry; used for the
-  real end-to-end accuracy experiments (smoke-scale models served on CPU).
+* ``cpu_wallclock`` — host clock around the jit-compiled entry, ending in
+  ``block_until_ready`` on whatever backend JAX runs (the CPU in tests,
+  the chip on a TPU host).  Rows it writes under a TPU hardware label must
+  come from a TPU: :func:`require_measuring_device` refuses otherwise.
 * ``tpu_analytical`` — the v5e roofline model over the compiled artifact
   (trip-aware hlo_cost): latency = max(flops/peak, bytes/bw).  Works at any
   model size with zero allocation; used for the full-size dedup accounting.
@@ -75,10 +76,21 @@ def tpu_analytical(fn: Callable, args: Sequence[Any]) -> float:
 ORACLES = {"cpu_wallclock": cpu_wallclock, "tpu_analytical": tpu_analytical}
 
 
-def measure(oracle: str, fn: Callable, args: Sequence[Any],
-            materialize: Callable = None) -> float:
-    if oracle == "cpu_wallclock":
-        if materialize is not None:
-            args = materialize(args)
-        return cpu_wallclock(fn, args)
-    return tpu_analytical(fn, args)
+def require_measuring_device(oracle: str, hardware: str) -> None:
+    """Refuse wall-clock rows labelled with a TPU the process does not
+    run on: a run that means to measure the chip and finds none fails
+    instead of writing CPU timings under the chip's name."""
+    if (oracle == "cpu_wallclock" and "tpu" in hardware.lower()
+            and jax.default_backend() != "tpu"):
+        raise RuntimeError(
+            f"oracle cpu_wallclock would time the {jax.default_backend()} "
+            f"backend but label the rows {hardware!r}; run on the TPU, or "
+            "label the rows with the measuring device's device_kind")
+
+
+def measure(oracle: str, fn: Callable, args: Sequence[Any]) -> float:
+    impl = ORACLES.get(oracle)
+    if impl is None:
+        raise ValueError(f"unknown oracle {oracle!r}; expected one of "
+                         f"{', '.join(sorted(ORACLES))}")
+    return impl(fn, args)

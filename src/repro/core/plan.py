@@ -40,6 +40,7 @@ from repro.core.profiler import (DoolyProf, EntryReport, ProfileReport,
                                  SweepConfig, validate_rows)
 from repro.core.runner import ModelTrace, trace_model
 from repro.core.signature import Signature
+from repro.runtime import refuse_child_processes
 
 #: (model name, attention backend, tp) — one profiled configuration
 ModelKey = Tuple[str, str, int]
@@ -725,7 +726,12 @@ def execute_plan(db: LatencyDB, plan: ProfilePlan, *, workers: int = 1,
     makespan is not tail-dominated) and streaming back in completion
     order; rows are bit-identical to a serial run either way.  Commit,
     journal-append, and ``progress`` failures are never swallowed — only
-    measurement failures are supervised."""
+    measurement failures are supervised.  On a TPU backend the pool is
+    refused (:func:`repro.runtime.refuse_child_processes`): only this
+    process can hold the chip."""
+    if workers > 1 or task_timeout is not None:
+        refuse_child_processes(
+            "execute_plan(workers>1 or task_timeout=...)")
     t0 = time.perf_counter()
     from repro.core.journal import PlanJournal
     from repro.core.supervisor import SupervisedPool

@@ -55,6 +55,7 @@ from repro.core.opset import (ModuleEntry, OpEntry, detach_op_entry,
 from repro.core.runner import ModelTrace, trace_model
 from repro.core.signature import (Signature, module_entry_signature,
                                   op_entry_signature)
+from repro.runtime import refuse_child_processes
 from repro.serving.context import (ModuleContext, cached_build_context,
                                    phases_for)
 
@@ -232,6 +233,7 @@ class DoolyProf:
                  hardware: str = "tpu-v5e",
                  sweep: Optional[SweepConfig] = None,
                  validation: Optional[ValidationPolicy] = None):
+        oracles.require_measuring_device(oracle, hardware)
         self.db = db
         self.oracle = oracle
         self.hardware = hardware
@@ -256,6 +258,7 @@ class DoolyProf:
                       workers: int = 1,
                       entries: Optional[List] = None) -> ProfileReport:
         if workers > 1:
+            refuse_child_processes("profile_model(workers>1)")
             # trace + resolve ONCE in the parent; workers get serialized
             # measurement tasks instead of re-tracing the model
             mt = trace or trace_model(cfg)

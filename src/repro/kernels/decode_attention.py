@@ -6,15 +6,19 @@ processes one (batch, kv-head) pair and one KV block; the whole GQA query
 group (H/KV heads) rides along in a single (group, D) VMEM block so the
 MXU sees a (group, bk) logits tile instead of H separate vector products.
 
-Per-request valid lengths arrive as a (B, 1) int32 array read from its own
-block; masking covers both the cache padding and an optional sliding
-window (kpos >= length - window).
+Per-request valid lengths arrive by scalar prefetch: a (B,) int32 array in
+SMEM, read by the kernel body and by the K/V index maps.  The index maps
+clamp the block index to the last block holding valid keys, so blocks past
+a request's length repeat the previous block index and are not fetched
+again.  Masking covers both the cache padding and an optional sliding
+window (kpos >= length - window).  Softmax statistics are (group, 1)
+columns, never 1-D vectors.
 
-Layout: q (B, KV, G, D)   k/v cache (B, KV, Smax, D)   lengths (B, 1)
+Layout: q (B, KV, G, D)   k/v cache (B, KV, Smax, D)   lengths (B,)
         -> out (B, KV, G, Dv)
 
 Validated in interpret mode against ``ref.decode_attention``
-(tests/test_kernels.py).
+(tests/test_kernels.py), and natively by chip_smoke.py.
 """
 from __future__ import annotations
 
@@ -26,15 +30,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
             acc_ref, m_ref, l_ref, *,
-            window: int, smax: int, bk: int, nk: int):
-    ik = pl.program_id(2)
+            window: int, bk: int, nk: int):
+    ib, ik = pl.program_id(0), pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
@@ -42,9 +44,9 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    length = len_ref[0, 0]
+    length = len_ref[ib]
     k_start = ik * bk
-    lo = jnp.where(window > 0, length - window, 0)
+    lo = length - window if window > 0 else 0
     relevant = (k_start < length) & (k_start + bk > lo)
 
     @pl.when(relevant)
@@ -60,19 +62,19 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
             mask &= kpos >= length - window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
+        m_prev = m_ref[...]                          # (g, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * corr
                         + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ()))))
         m_ref[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def decode_attention(q, k_cache, v_cache, lengths, *, window: int = 0,
@@ -80,30 +82,34 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window: int = 0,
     """q: (B,KV,G,D)  k/v: (B,KV,Smax,D[v])  lengths: (B,) -> (B,KV,G,Dv)."""
     b, kv, g, d = q.shape
     smax, dv = k_cache.shape[2], v_cache.shape[3]
-    bk = block_k or min(512, smax)
-    bk = min(bk, smax)
+    bk = min(block_k or 512, smax)
     nk = pl.cdiv(smax, bk)
-    lengths2 = lengths.reshape(b, 1).astype(jnp.int32)
 
-    kernel = functools.partial(_kernel, window=window, smax=smax, bk=bk, nk=nk)
-    out = pl.pallas_call(
+    def kv_map(ib, ih, ik, lens):
+        last = jnp.maximum(lens[ib] - 1, 0) // bk
+        return (ib, ih, jnp.minimum(ik, last), 0)
+
+    kernel = functools.partial(_kernel, window=window, bk=bk, nk=nk)
+    return pl.pallas_call(
         kernel,
-        grid=(b, kv, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda ib, ih, ik: (ib, 0)),
-            pl.BlockSpec((1, 1, g, d), lambda ib, ih, ik: (ib, ih, 0, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda ib, ih, ik: (ib, ih, ik, 0)),
-            pl.BlockSpec((1, 1, bk, dv), lambda ib, ih, ik: (ib, ih, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, dv), lambda ib, ih, ik: (ib, ih, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, kv, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, g, d),
+                             lambda ib, ih, ik, lens: (ib, ih, 0, 0)),
+                pl.BlockSpec((1, 1, bk, d), kv_map),
+                pl.BlockSpec((1, 1, bk, dv), kv_map),
+            ],
+            out_specs=pl.BlockSpec((1, 1, g, dv),
+                                   lambda ib, ih, ik, lens: (ib, ih, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((g, dv), jnp.float32),
+                pltpu.VMEM((g, 1), jnp.float32),
+                pltpu.VMEM((g, 1), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g, dv), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(lengths2, q, k_cache, v_cache)
-    return out
+    )(lengths.astype(jnp.int32), q, k_cache, v_cache)

@@ -10,8 +10,13 @@ sequence dim contiguously in VMEM.
 Supports: causal masking, sliding window, q_offset (chunked prefill).
 The forward also emits the LSE needed by the backward kernels.
 
+Per-row softmax statistics (running max, normalizer, LSE, delta) are
+(bq, 1) columns, never 1-D vectors: a (B, H, Sq, 1) array tiles as
+(bq, 1) blocks, which meets Mosaic's rule that a block's last two dims be
+multiples of (8, 128) or the array's full dims.
+
 Validated in interpret mode against ``ref.attention`` / jax.grad of the
-reference (tests/test_kernels.py).
+reference (tests/test_kernels.py), and natively by chip_smoke.py.
 """
 from __future__ import annotations
 
@@ -73,26 +78,26 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             mask &= kpos > qpos - window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                               # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-        acc_ref[...] = (acc_ref[...] * corr[:, None]
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * corr
                         + jax.lax.dot_general(p, v, (((1,), (0,)), ((), ()))))
         m_ref[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
         lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
 def flash_attention_fwd(q, k, v, *, causal=True, window=0, q_offset=0,
                         interpret=False):
-    """q: (B,H,Sq,D)  k,v: (B,KV,Sk,D)  ->  out (B,H,Sq,Dv), lse (B,H,Sq)."""
+    """q: (B,H,Sq,D)  k,v: (B,KV,Sk,D)  ->  out (B,H,Sq,Dv), lse (B,H,Sq,1)."""
     b, h, sq, d = q.shape
     kv, sk, dv = k.shape[1], k.shape[2], v.shape[3]
     group = h // kv
@@ -113,16 +118,16 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0, q_offset=0,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, dv), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda ib, ih, iq, ik: (ib, ih, iq)),
+            pl.BlockSpec((1, 1, bq, 1), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, dv), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
@@ -167,9 +172,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             mask &= kpos <= qpos
         if window > 0:
             mask &= kpos > qpos - window
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         acc_ref[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())))
 
     @pl.when(ik == nk - 1)
@@ -212,10 +217,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             mask &= kpos <= qpos
         if window > 0:
             mask &= kpos > qpos - window
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)           # (bq,bk)
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)                     # (bq,bk)
         dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())))
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())))
-        ds = p * (dp - delta[:, None]) * scale                         # (bq,bk)
+        ds = p * (dp - delta) * scale                                  # (bq,bk)
         dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())))
 
     @pl.when(iq == nq - 1)
@@ -226,15 +231,17 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=0,
                         q_offset=0, interpret=False):
-    """Returns (dq, dk, dv) with dk/dv per *query* head (B,H,Sk,D);
-    the GQA group-sum happens in ops.py."""
+    """``lse`` is the forward's (B,H,Sq,1).  Returns (dq, dk, dv) with
+    dk/dv per *query* head (B,H,Sk,D); the GQA group-sum happens in
+    ops.py."""
     b, h, sq, d = q.shape
     kv, sk, dv_dim = k.shape[1], k.shape[2], v.shape[3]
     group = h // kv
     bq, bk = _block_sizes(sq, sk, d)
     nq, nk = pl.cdiv(sq, bq), pl.cdiv(sk, bk)
 
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1, keepdims=True)                          # (B,H,Sq,1)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, window=window,
@@ -245,8 +252,8 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=0,
             pl.BlockSpec((1, 1, bk, d), lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)),
             pl.BlockSpec((1, 1, bk, dv_dim), lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)),
             pl.BlockSpec((1, 1, bq, dv_dim), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda ib, ih, iq, ik: (ib, ih, iq)),
-            pl.BlockSpec((1, 1, bq), lambda ib, ih, iq, ik: (ib, ih, iq)),
+            pl.BlockSpec((1, 1, bq, 1), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
@@ -263,8 +270,8 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, window=0,
             pl.BlockSpec((1, 1, bk, d), lambda ib, ih, ik, iq: (ib, ih // group, ik, 0)),
             pl.BlockSpec((1, 1, bk, dv_dim), lambda ib, ih, ik, iq: (ib, ih // group, ik, 0)),
             pl.BlockSpec((1, 1, bq, dv_dim), lambda ib, ih, ik, iq: (ib, ih, iq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda ib, ih, ik, iq: (ib, ih, iq)),
-            pl.BlockSpec((1, 1, bq), lambda ib, ih, ik, iq: (ib, ih, iq)),
+            pl.BlockSpec((1, 1, bq, 1), lambda ib, ih, ik, iq: (ib, ih, iq, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda ib, ih, ik, iq: (ib, ih, iq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda ib, ih, ik, iq: (ib, ih, ik, 0)),

@@ -7,12 +7,21 @@ TPU-native layout: grid (batch, d_inner blocks, seq chunks) with the chunk
 axis sequential ("arbitrary") so the hidden state lives in a VMEM scratch
 accumulator across chunks — the HBM traffic is exactly one read of
 (x, dt, B, C) and one write of y, with no O(S * Di * N) intermediate like the
-pure-jnp associative scan materializes.  Within a chunk the recurrence runs
-as a fori_loop of (bd, N) VPU ops.
+pure-jnp associative scan materializes.
+
+The state is kept transposed, (N, bd): d_inner runs along the 128-wide
+lanes and the small state dim along sublanes, so each step is a handful of
+dense (N, bd) VPU ops and ``y_t`` is a sublane reduction.  The chunk is
+walked in groups of ``rows`` steps (one sublane tile: 8 rows of float32,
+16 of bfloat16): each group loads its x/dt/B/C rows with one aligned
+dynamic slice, runs its steps unrolled on values, and stores its y rows
+with one aligned slice — Mosaic refuses single-row dynamic loads and
+stores it cannot prove tile-aligned.
 
 Forward-only (serving / profiling); training uses the chunked associative
 scan in models/mamba.py.  Validated in interpret mode against
-``ref.selective_scan`` (tests/test_kernels.py).
+``ref.selective_scan`` (tests/test_kernels.py), and natively by
+chip_smoke.py.
 """
 from __future__ import annotations
 
@@ -23,36 +32,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import tpu_compiler_params
-
 
 def _kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, h0_ref,
-            y_ref, hout_ref, h_ref, *, t: int, nc: int, seq: int):
+            y_ref, hout_ref, h_ref, *, t: int, nc: int, rows: int):
     ic = pl.program_id(2)
 
     @pl.when(ic == 0)
     def _init():
         h_ref[...] = h0_ref[0].astype(jnp.float32)
 
-    a = a_ref[...].astype(jnp.float32)              # (bd, n)
-    d = d_ref[...].astype(jnp.float32)              # (bd,)
+    a = a_ref[...].astype(jnp.float32)              # (n, bd)
+    d = d_ref[...].astype(jnp.float32)              # (1, bd)
+    row_id = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
 
-    def step(i, h):
-        dt_i = dt_ref[0, pl.ds(i, 1)][0].astype(jnp.float32)   # (bd,)
-        x_i = x_ref[0, pl.ds(i, 1)][0].astype(jnp.float32)     # (bd,)
-        b_i = b_ref[0, pl.ds(i, 1)][0].astype(jnp.float32)     # (n,)
-        c_i = c_ref[0, pl.ds(i, 1)][0].astype(jnp.float32)     # (n,)
-        dA = jnp.exp(dt_i[:, None] * a)                        # (bd, n)
-        h_new = dA * h + (dt_i * x_i)[:, None] * b_i[None, :]
-        y = jnp.sum(h_new * c_i[None, :], axis=-1) + d * x_i
-        # mask padding steps past the true sequence length
-        valid = ic * t + i < seq
-        y_ref[0, pl.ds(i, 1), :] = jnp.where(
-            valid, y, 0.0).astype(y_ref.dtype)[None, :]
-        return jnp.where(valid, h_new, h)
+    def group(j, h):
+        base = pl.multiple_of(j * rows, rows)
+        xs = x_ref[0, pl.ds(base, rows), :].astype(jnp.float32)    # (r, bd)
+        dts = dt_ref[0, pl.ds(base, rows), :].astype(jnp.float32)  # (r, bd)
+        bs = b_ref[0, pl.ds(base, rows), :].astype(jnp.float32).T  # (n, r)
+        cs = c_ref[0, pl.ds(base, rows), :].astype(jnp.float32).T  # (n, r)
+        ys = jnp.zeros(xs.shape, jnp.float32)
+        for r in range(rows):
+            dt_i, x_i = dts[r:r + 1], xs[r:r + 1]                  # (1, bd)
+            dA = jnp.exp(dt_i * a)                                 # (n, bd)
+            h = dA * h + (dt_i * x_i) * bs[:, r:r + 1]
+            y = jnp.sum(h * cs[:, r:r + 1], axis=0,
+                        keepdims=True) + d * x_i                   # (1, bd)
+            ys = jnp.where(row_id == r, y, ys)
+        y_ref[0, pl.ds(base, rows), :] = ys.astype(y_ref.dtype)
+        return h
 
-    h = jax.lax.fori_loop(0, t, step, h_ref[...], unroll=False)
-    h_ref[...] = h
+    h_ref[...] = jax.lax.fori_loop(0, t // rows, group, h_ref[...])
 
     @pl.when(ic == nc - 1)
     def _finalize():
@@ -67,22 +77,24 @@ def mamba_scan(x, dt, A, Bc, Cc, D, h0=None, *, block_d: int = 0,
     """
     b, s, di = x.shape
     n = A.shape[1]
-    t = min(chunk, s)
+    rows = 8 * max(1, 4 // jnp.dtype(x.dtype).itemsize)   # one sublane tile
+    t = pl.cdiv(min(chunk, s), rows) * rows
     nc = pl.cdiv(s, t)
+    # zero padding is inert: dt = 0 makes exp(dt * A) = 1 and dt * x = 0,
+    # so padded steps carry h through unchanged and emit y = 0
     pad = nc * t - s
     if pad:
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
         dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
         Bc = jnp.pad(Bc, ((0, 0), (0, pad), (0, 0)))
         Cc = jnp.pad(Cc, ((0, 0), (0, pad), (0, 0)))
-    bd = block_d or min(di, 512)
-    bd = min(bd, di)
+    bd = min(block_d or 512, di)
     assert di % bd == 0, (di, bd)
     nd = di // bd
     if h0 is None:
         h0 = jnp.zeros((b, di, n), jnp.float32)
 
-    kernel = functools.partial(_kernel, t=t, nc=nc, seq=s)
+    kernel = functools.partial(_kernel, t=t, nc=nc, rows=rows)
     y, h = pl.pallas_call(
         kernel,
         grid=(b, nd, nc),
@@ -91,21 +103,21 @@ def mamba_scan(x, dt, A, Bc, Cc, D, h0=None, *, block_d: int = 0,
             pl.BlockSpec((1, t, bd), lambda ib, id_, ic: (ib, ic, id_)),
             pl.BlockSpec((1, t, n), lambda ib, id_, ic: (ib, ic, 0)),
             pl.BlockSpec((1, t, n), lambda ib, id_, ic: (ib, ic, 0)),
-            pl.BlockSpec((bd, n), lambda ib, id_, ic: (id_, 0)),
-            pl.BlockSpec((bd,), lambda ib, id_, ic: (id_,)),
-            pl.BlockSpec((1, bd, n), lambda ib, id_, ic: (ib, id_, 0)),
+            pl.BlockSpec((n, bd), lambda ib, id_, ic: (0, id_)),
+            pl.BlockSpec((1, bd), lambda ib, id_, ic: (0, id_)),
+            pl.BlockSpec((1, n, bd), lambda ib, id_, ic: (ib, 0, id_)),
         ],
         out_specs=[
             pl.BlockSpec((1, t, bd), lambda ib, id_, ic: (ib, ic, id_)),
-            pl.BlockSpec((1, bd, n), lambda ib, id_, ic: (ib, id_, 0)),
+            pl.BlockSpec((1, n, bd), lambda ib, id_, ic: (ib, 0, id_)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, nc * t, di), x.dtype),
-            jax.ShapeDtypeStruct((b, di, n), jnp.float32),
+            jax.ShapeDtypeStruct((b, n, di), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        scratch_shapes=[pltpu.VMEM((n, bd), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt, Bc, Cc, A, D, h0)
-    return y[:, :s], h
+    )(x, dt, Bc, Cc, A.T, D.reshape(1, di), h0.transpose(0, 2, 1))
+    return y[:, :s], h.transpose(0, 2, 1)

@@ -1,8 +1,9 @@
 """jit'd wrappers around the Pallas kernels with custom VJPs.
 
 Model-facing layout is (B, S, H, D); kernels use head-major (B, H, S, D).
-On non-TPU backends the kernels run in interpret mode (Python execution of
-the kernel body) so the same code path is validated on CPU.
+On the CPU backend the kernels run in interpret mode (Python execution of
+the kernel body) so the same code path is validated there; on any other
+backend they compile natively.
 """
 from __future__ import annotations
 
@@ -16,7 +17,7 @@ from repro.kernels import mamba_scan as ms
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -72,9 +73,7 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window: int = 0):
     """q (B,1,H,D)  caches (B,S,KV,D[v])  lengths (B,) -> (B,1,H,Dv)."""
     b, _, h, d = q.shape
     kv = k_cache.shape[2]
-    g = h // kv
-    qh = q.reshape(b, kv, g, d) if kv * g == h else q.reshape(b, kv, g, d)
-    qh = q[:, 0].reshape(b, kv, g, d)
+    qh = q[:, 0].reshape(b, kv, h // kv, d)
     out = da.decode_attention(qh, k_cache.transpose(0, 2, 1, 3),
                               v_cache.transpose(0, 2, 1, 3), lengths,
                               window=window, interpret=_interpret())
@@ -86,5 +85,5 @@ def decode_attention(q, k_cache, v_cache, lengths, *, window: int = 0):
 # ---------------------------------------------------------------------------
 
 def selective_scan(x, dt, A, Bc, Cc, D, h0=None):
-    """Pallas chunked scan; falls back to interpret mode off-TPU."""
+    """Pallas chunked scan (interpret mode on the CPU backend)."""
     return ms.mamba_scan(x, dt, A, Bc, Cc, D, h0=h0, interpret=_interpret())

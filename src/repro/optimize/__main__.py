@@ -33,6 +33,7 @@ from repro._cli import (add_db_arg, add_hardware_arg, add_json_arg,
 from repro.api import ProfileStore
 from repro.optimize.autoscale import AutoscalePolicy, simulate_autoscale
 from repro.optimize.search import SLO, OptimizeSpec, Optimizer
+from repro.runtime import use_compile_cache
 from repro.sweep.grid import SchedSpec, WorkloadSpec, expand_grid
 from repro.sweep.__main__ import PROFILE_SWEEP
 
@@ -175,4 +176,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     sys.exit(main())

@@ -43,6 +43,7 @@ from repro._cli import (add_db_arg, add_hardware_arg, add_json_arg, emit,
 from repro.api import ProfileStore
 from repro.configs import get_config, get_smoke_config
 from repro.core.profiler import QUICK_SWEEP, SweepConfig
+from repro.runtime import use_compile_cache
 
 #: CLI-scale sweep: small enough to demo a corpus plan in seconds
 CLI_SWEEP = QUICK_SWEEP
@@ -300,4 +301,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     sys.exit(main())

@@ -49,6 +49,7 @@ from repro._cli import (add_db_arg, add_hardware_arg, add_json_arg,
                         add_workload_trace_arg, emit, json_to_stdout)
 from repro.api import ProfileStore
 from repro.core.profiler import SweepConfig
+from repro.runtime import use_compile_cache
 from repro.sweep.grid import (SchedSpec, WorkloadSpec, expand_grid,
                               grid_summary)
 from repro.sweep.runner import SweepResult, compare_results, compare_table
@@ -236,4 +237,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     sys.exit(main())

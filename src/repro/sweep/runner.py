@@ -63,6 +63,7 @@ import numpy as np
 from repro.api.store import ProfileStore
 from repro.configs import get_smoke_config
 from repro.core.database import LatencyDB
+from repro.runtime import cpu_only_children
 from repro.serving.scheduler import Request
 from repro.sim.events import StaggeredTrace, run_events
 from repro.sim.metrics import request_metrics
@@ -708,10 +709,14 @@ class Sweep:
         summaries: List[Dict[str, float]] = []
         ctx = multiprocessing.get_context("spawn")
         with ProcessPoolExecutor(max_workers=eff, mp_context=ctx) as pool:
-            futs = {pool.submit(_eval_worker, store_kw, sweep_kw,
-                                [scenarios[i] for i in bundle],
-                                on_error): bundle
-                    for bundle in bundles}
+            # workers start inside submit(); they only price from fits,
+            # so they run on the CPU backend and never contend for a chip
+            # this process may hold
+            with cpu_only_children():
+                futs = {pool.submit(_eval_worker, store_kw, sweep_kw,
+                                    [scenarios[i] for i in bundle],
+                                    on_error): bundle
+                        for bundle in bundles}
             for fut in as_completed(futs):
                 bundle = futs[fut]
                 try:
