@@ -9,8 +9,7 @@ temporary bytes must fit one chip's 16 GiB.  Nothing runs, so this says
 nothing about results or times.
 
 The topology is described inside a module-scoped fixture — never at
-import — and all such tests live in this one file (see the
-on-chip-measurement guide, section 2).
+import — and all such tests live in this one file.
 """
 import functools
 import os
