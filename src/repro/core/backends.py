@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.parallel.roofline import HBM_BW, PEAK_FLOPS
+from repro.runtime import span
 
 
 _DISPATCH_FLOOR: list = []
@@ -48,17 +49,24 @@ def _dispatch_floor() -> float:
 def cpu_wallclock(fn: Callable, args: Sequence[Any], *, repeats: int = 5,
                   warmup: int = 2) -> float:
     """Median wall-clock seconds of one jitted call (concrete args),
-    harness dispatch floor subtracted."""
+    harness dispatch floor subtracted.
+
+    Spans: ``oracle.first_call`` (tracing, lowering, compiling or loading,
+    and running the program), ``oracle.warmup`` (the other warm-up
+    calls), ``oracle.timed`` (around the timed repeats, never inside
+    one)."""
     jitted = jax.jit(fn)
-    for _ in range(warmup):
-        out = jitted(*args)
-        jax.block_until_ready(out)
+    for i in range(warmup):
+        with span("oracle.first_call" if i == 0 else "oracle.warmup"):
+            out = jitted(*args)
+            jax.block_until_ready(out)
     times = []
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        out = jitted(*args)
-        jax.block_until_ready(out)
-        times.append(time.perf_counter() - t0)
+    with span("oracle.timed"):
+        for _ in range(repeats):
+            t0 = time.perf_counter()
+            out = jitted(*args)
+            jax.block_until_ready(out)
+            times.append(time.perf_counter() - t0)
     times.sort()
     med = times[len(times) // 2]
     return max(med - _dispatch_floor(), med * 0.05, 1e-8)

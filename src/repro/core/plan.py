@@ -40,7 +40,7 @@ from repro.core.profiler import (DoolyProf, EntryReport, ProfileReport,
                                  SweepConfig, validate_rows)
 from repro.core.runner import ModelTrace, trace_model
 from repro.core.signature import Signature
-from repro.runtime import refuse_child_processes
+from repro.runtime import refuse_child_processes, span
 
 #: (model name, attention backend, tp) — one profiled configuration
 ModelKey = Tuple[str, str, int]
@@ -758,11 +758,12 @@ def execute_plan(db: LatencyDB, plan: ProfilePlan, *, workers: int = 1,
     def _commit(task: PlanTask, rows: List[Tuple]):
         nonlocal measured, rows_written
         validate_rows(rows, where=f"task {task.task_id}")
-        with db.transaction():
-            db.insert_signatures_bulk([sig_by_hash[task.sig_hash]])
-            db.add_measurements_bulk(rows)
-        if journal is not None:
-            journal.record_done(task.task_id)
+        with span("profile.commit"):
+            with db.transaction():
+                db.insert_signatures_bulk([sig_by_hash[task.sig_hash]])
+                db.add_measurements_bulk(rows)
+            if journal is not None:
+                journal.record_done(task.task_id)
         measured += 1
         rows_written += len(rows)
         if progress is not None:
@@ -806,24 +807,26 @@ def execute_plan(db: LatencyDB, plan: ProfilePlan, *, workers: int = 1,
         elif todo:
             measure = _resolve_measure_fn(prof, measure_fn)
             for task in todo:
-                attempts = 0
-                while True:
-                    attempts += 1
-                    try:
-                        rows = validate_rows(
-                            measure(task.payload, task.cfg, task.backend),
-                            where=f"task {task.task_id}")
-                    except Exception as e:      # noqa: BLE001
-                        if attempts > max_retries:
-                            _quarantine(task,
-                                        f"{type(e).__name__}: {e}")
-                            break
-                        retried += 1
-                        time.sleep(retry_backoff_s
-                                   * (2 ** (attempts - 1)))
-                        continue
-                    _commit(task, rows)
-                    break
+                with span("profile.task"):
+                    attempts = 0
+                    while True:
+                        attempts += 1
+                        try:
+                            rows = validate_rows(
+                                measure(task.payload, task.cfg,
+                                        task.backend),
+                                where=f"task {task.task_id}")
+                        except Exception as e:      # noqa: BLE001
+                            if attempts > max_retries:
+                                _quarantine(task,
+                                            f"{type(e).__name__}: {e}")
+                                break
+                            retried += 1
+                            time.sleep(retry_backoff_s
+                                       * (2 ** (attempts - 1)))
+                            continue
+                        _commit(task, rows)
+                        break
 
         # idempotent tail: every signature (satisfied ones included) and
         # the per-model call-graph counts, one transaction.  Quarantined

@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import math
 import re
-import time
 from concurrent.futures import ProcessPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
@@ -55,7 +54,7 @@ from repro.core.opset import (ModuleEntry, OpEntry, detach_op_entry,
 from repro.core.runner import ModelTrace, trace_model
 from repro.core.signature import (Signature, module_entry_signature,
                                   op_entry_signature)
-from repro.runtime import refuse_child_processes
+from repro.runtime import refuse_child_processes, span
 from repro.serving.context import (ModuleContext, cached_build_context,
                                    phases_for)
 
@@ -195,7 +194,6 @@ class ProfileReport:
     model: str
     backend: str
     entries: List[EntryReport] = field(default_factory=list)
-    trace_s: float = 0.0
 
     @property
     def spent_s(self) -> float:
@@ -273,7 +271,6 @@ class DoolyProf:
                                           entries=entries)
             finally:
                 self._premeasured, self._entry_sigs = prev, prev_sigs
-        t0 = time.time()
         # discard any staging left by a previous profile_model that raised —
         # stale pending rows would corrupt this model's dedup accounting
         self._clear_pending()
@@ -281,7 +278,6 @@ class DoolyProf:
             mt = trace or trace_model(cfg)
             entries = find_runnable_set(mt.trace)
         report = ProfileReport(model=cfg.name, backend=backend)
-        report.trace_s = time.time() - t0
         config_id = self.db.config_id(cfg.name, backend, self.hardware, tp)
 
         counts: Dict[Tuple[str, str], int] = {}
@@ -398,8 +394,10 @@ class DoolyProf:
         if payload[0] == "module":
             _, kind, window, sig_hash = payload
             for phase in phases_for(kind, cfg):
-                mc = cached_build_context(cfg, kind, phase=phase,
-                                          backend=backend, window=window)
+                with span("profile.context"):
+                    mc = cached_build_context(cfg, kind, phase=phase,
+                                              backend=backend,
+                                              window=window)
                 for toks, reqs, ctx in self._phase_points(phase):
                     lat_us = self._measure_module(mc, toks, reqs, ctx) * 1e6
                     rows.append((sig_hash, self.hardware, phase, toks, reqs,
@@ -524,9 +522,10 @@ class DoolyProf:
     def _profile_stateful(self, entry: ModuleEntry, cfg, backend, config_id
                           ) -> Optional[EntryReport]:
         window = window_for_path(cfg, entry.node.path)
-        ctx_pre = cached_build_context(cfg, entry.context_kind,
-                                       phase="prefill", backend=backend,
-                                       window=window)
+        with span("profile.context"):
+            ctx_pre = cached_build_context(cfg, entry.context_kind,
+                                           phase="prefill", backend=backend,
+                                           window=window)
         sig = (self._entry_sigs.get(id(entry))
                or module_entry_signature(entry, ctx_pre))
         self._record_sig(sig)
@@ -534,9 +533,13 @@ class DoolyProf:
         variant = self._variant(ctx_pre)
         cost = 0.0
         for phase in phases_for(entry.context_kind, cfg):
-            mc = ctx_pre if phase == "prefill" else cached_build_context(
-                cfg, entry.context_kind, phase="decode", backend=backend,
-                window=window)
+            if phase == "prefill":
+                mc = ctx_pre
+            else:
+                with span("profile.context"):
+                    mc = cached_build_context(
+                        cfg, entry.context_kind, phase="decode",
+                        backend=backend, window=window)
             for toks, reqs, ctx in self._phase_points(phase):
                 key = (phase, toks, reqs, ctx)
                 if reused:
@@ -583,16 +586,20 @@ class DoolyProf:
         return ""
 
     def _measure_op(self, entry: OpEntry, toks, reqs) -> float:
-        fn, args = entry.jit_callable(toks=toks, reqs=reqs)
+        # jit_callable draws the operator's operands (and binds it)
+        with span("profile.operands"):
+            fn, args = entry.jit_callable(toks=toks, reqs=reqs)
         return self.validation.check(
             lambda: oracles.measure(self.oracle, fn, args),
             f"op {entry.kind} toks={toks} reqs={reqs}")
 
     def _measure_module(self, mc: ModuleContext, toks, reqs, ctx) -> float:
-        args = mc.abstract_inputs(max(toks, 1), max(reqs, 1), max(ctx, 1))
-        full = (mc.params,) + tuple(args)
-        if self.oracle == "cpu_wallclock":
-            full = mc.materialize(full)
+        with span("profile.operands"):
+            args = mc.abstract_inputs(max(toks, 1), max(reqs, 1),
+                                      max(ctx, 1))
+            full = (mc.params,) + tuple(args)
+            if self.oracle == "cpu_wallclock":
+                full = mc.materialize(full)
         return self.validation.check(
             lambda: oracles.measure(self.oracle, mc.fn, full),
             f"module {mc.kind} toks={toks} reqs={reqs} ctx={ctx}")

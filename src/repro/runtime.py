@@ -13,6 +13,8 @@ price from fitted latency models start them on the CPU backend
 ``chip_smoke.py``, never at import: a ``JAX_COMPILATION_CACHE_DIR`` set in
 the environment is left to JAX, and otherwise the persistent cache goes to
 one fixed, git-ignored path inside the checkout.
+
+:func:`span` names a stretch of host work in a ``jax.profiler`` trace.
 """
 from __future__ import annotations
 
@@ -62,3 +64,19 @@ def use_compile_cache() -> Optional[Path]:
         return None
     jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
     return COMPILE_CACHE_DIR
+
+
+#: prefix of every program span in a ``jax.profiler`` trace
+SPAN_PREFIX = "dooly."
+
+
+def span(name: str, **attrs) -> jax.profiler.TraceAnnotation:
+    """A host span ``dooly.<name>`` in any ``jax.profiler`` trace that is
+    recording, with ``attrs`` as its arguments; with no trace recording
+    it costs what a ``contextlib.nullcontext`` costs.
+
+    The spans sit on the same clock as the device's ops, so an operator
+    who wraps a run in ``jax.profiler.trace(logdir)`` sees in TensorBoard
+    or Perfetto which host work each idle stretch of the device waited
+    on.  A span never wraps a timed interval: it would add to the time."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **attrs)

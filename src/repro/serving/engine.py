@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import build_model
+from repro.runtime import span
 from repro.serving.scheduler import (IterationPlan, Request, Scheduler,
                                      SchedulerConfig)
 
@@ -70,9 +71,11 @@ class Engine:
         self.clock = 0.0
         self.records: List[IterationRecord] = []
 
-        self._decode_fn = jax.jit(
-            lambda p, c, t, l: self.model.decode_step(p, c, t, l,
-                                                      impl=impl))
+        def decode_step(p, c, t, l):
+            return self.model.decode_step(p, c, t, l, impl=impl)
+
+        # named functions: the device trace shows ``jit_decode_step``
+        self._decode_fn = jax.jit(decode_step)
         self._chunk_fns: Dict[int, Any] = {}
         self.warmup()
 
@@ -80,9 +83,10 @@ class Engine:
 
     def _chunk_fn(self, c: int):
         if c not in self._chunk_fns:
-            self._chunk_fns[c] = jax.jit(
-                lambda p, cache, toks, lens, last: self.model.prefill_chunk(
-                    p, cache, toks, lens, impl=self.impl, last_pos=last))
+            def prefill_chunk(p, cache, toks, lens, last):
+                return self.model.prefill_chunk(p, cache, toks, lens,
+                                                impl=self.impl, last_pos=last)
+            self._chunk_fns[c] = jax.jit(prefill_chunk)
         return self._chunk_fns[c]
 
     def warmup(self):
@@ -113,41 +117,65 @@ class Engine:
     # ------------------------------------------------------------------
 
     def execute(self, plan: IterationPlan) -> float:
-        """Run one iteration plan; returns measured model seconds."""
-        t0 = time.perf_counter()
-        new_tokens: Dict[int, int] = {}
-        for chunk in plan.prefills:
-            r = chunk.req
-            # SSM state is sequential: pad tokens would corrupt it, so
-            # mamba/hybrid archs run exact-length chunks (no bucketing)
-            b = chunk.length if self.cfg.ssm_state > 0 else \
-                bucket_chunk(chunk.length, self.sched.config.chunk_size)
-            ids = r.prompt[chunk.start:chunk.start + chunk.length]
-            ids = ids + [0] * (b - chunk.length)        # pad to the bucket
-            toks = jnp.asarray(ids, jnp.int32)[None]
-            lens = jnp.asarray([chunk.start], jnp.int32)
-            last = jnp.asarray([chunk.length - 1], jnp.int32)
-            fn = self._chunk_fn(b)
-            logits, row = fn(self.params, self._row_cache(r.slot), toks,
-                             lens, last)
-            jax.block_until_ready(logits)
-            self._write_row(r.slot, row)
-            self.lengths = self.lengths.at[r.slot].set(
-                chunk.start + chunk.length)
-            if chunk.start + chunk.length >= r.prompt_len:
-                new_tokens[r.rid] = int(jnp.argmax(logits[0]))
-        if plan.decodes:
-            # replay mode: deterministic dummy token ids (latency-identical)
-            toks = jnp.zeros((self.sched.config.max_num_seqs,), jnp.int32)
-            for r in plan.decodes:
-                toks = toks.at[r.slot].set(1 + (r.generated % 7))
-            logits, self.cache = self._decode_fn(
-                self.params, self.cache, toks, self.lengths)
-            jax.block_until_ready(logits)
-            for r in plan.decodes:
-                new_tokens[r.rid] = int(jnp.argmax(logits[r.slot]))
-                self.lengths = self.lengths.at[r.slot].add(1)
-        return time.perf_counter() - t0
+        """Run one iteration plan; returns measured model seconds.
+
+        Host spans (:func:`repro.runtime.span`) split the iteration in a
+        ``jax.profiler`` trace: per chunk ``engine.prefill_chunk``, then
+        ``engine.decode``, each made of ``engine.inputs``,
+        ``engine.dispatch`` (the step program's call), ``engine.sync``
+        (waiting on the device), and ``engine.write_row`` /
+        ``engine.readback`` (host work on the outputs)."""
+        with span("engine.execute"):
+            t0 = time.perf_counter()
+            new_tokens: Dict[int, int] = {}
+            for chunk in plan.prefills:
+                r = chunk.req
+                with span("engine.prefill_chunk", rid=r.rid):
+                    # SSM state is sequential: pad tokens would corrupt
+                    # it, so mamba/hybrid archs run exact-length chunks
+                    b = chunk.length if self.cfg.ssm_state > 0 else \
+                        bucket_chunk(chunk.length,
+                                     self.sched.config.chunk_size)
+                    with span("engine.inputs"):
+                        ids = r.prompt[chunk.start:chunk.start
+                                       + chunk.length]
+                        ids = ids + [0] * (b - chunk.length)    # pad
+                        toks = jnp.asarray(ids, jnp.int32)[None]
+                        lens = jnp.asarray([chunk.start], jnp.int32)
+                        last = jnp.asarray([chunk.length - 1], jnp.int32)
+                        row = self._row_cache(r.slot)
+                    with span("engine.dispatch"):
+                        logits, row = self._chunk_fn(b)(
+                            self.params, row, toks, lens, last)
+                    with span("engine.sync"):
+                        jax.block_until_ready(logits)
+                    with span("engine.write_row"):
+                        self._write_row(r.slot, row)
+                        self.lengths = self.lengths.at[r.slot].set(
+                            chunk.start + chunk.length)
+                    if chunk.start + chunk.length >= r.prompt_len:
+                        with span("engine.readback"):
+                            new_tokens[r.rid] = int(jnp.argmax(logits[0]))
+            if plan.decodes:
+                with span("engine.decode"):
+                    # replay mode: deterministic dummy token ids
+                    # (latency-identical)
+                    with span("engine.inputs"):
+                        toks = jnp.zeros((self.sched.config.max_num_seqs,),
+                                         jnp.int32)
+                        for r in plan.decodes:
+                            toks = toks.at[r.slot].set(1 + (r.generated % 7))
+                    with span("engine.dispatch"):
+                        logits, self.cache = self._decode_fn(
+                            self.params, self.cache, toks, self.lengths)
+                    with span("engine.sync"):
+                        jax.block_until_ready(logits)
+                    with span("engine.readback"):
+                        for r in plan.decodes:
+                            new_tokens[r.rid] = int(
+                                jnp.argmax(logits[r.slot]))
+                            self.lengths = self.lengths.at[r.slot].add(1)
+            return time.perf_counter() - t0
 
     # ------------------------------------------------------------------
 

@@ -1,9 +1,11 @@
-"""One process per chip, no hidden oracle fallback, and the compile cache.
+"""One process per chip, no hidden oracle fallback, the compile cache, and
+where the program's spans may not go.
 
 The TPU cases steer ``jax.default_backend`` itself: no option of the
 program selects them."""
 import multiprocessing as mp
 import os
+import re
 
 import jax
 import pytest
@@ -86,3 +88,38 @@ def test_compile_cache_defers_to_the_environment(monkeypatch):
     assert path.parent == type(path)(REPO)
     with open(os.path.join(REPO, ".gitignore")) as f:
         assert f"{path.name}/" in f.read().split()
+
+
+#: a call of ``span`` (not ``makespan``)
+SPAN_CALL = re.compile(r"(?<!\w)span\(")
+
+
+def test_span_names_program_work_under_one_prefix():
+    s = runtime.span("engine.prefill_chunk", rid=3)
+    assert isinstance(s, jax.profiler.TraceAnnotation)
+    assert runtime.SPAN_PREFIX == "dooly."
+    with s, runtime.span("engine.sync"):      # no trace recording: inert
+        pass
+
+
+@pytest.mark.parametrize("module", [
+    "repro.serving.scheduler", "repro.sim.events", "repro.sim.metrics",
+    "repro.sim.replay", "repro.sim.simulator", "repro.sim.workload"])
+def test_scheduler_and_simulator_hold_no_span(module):
+    """The simulator drives the scheduler millions of times in a sweep."""
+    import importlib
+    import inspect
+    src = inspect.getsource(importlib.import_module(module))
+    assert not SPAN_CALL.search(src) and "TraceAnnotation" not in src
+
+
+def test_no_span_inside_a_timed_repeat():
+    """A span between a repeat's start and end clocks would add to the
+    latency written to the database."""
+    import inspect
+    lines = inspect.getsource(oracles.cpu_wallclock).splitlines()
+    start = next(i for i, ln in enumerate(lines) if "t0 = time." in ln)
+    end = next(i for i, ln in enumerate(lines) if "times.append(" in ln)
+    assert start < end
+    assert not any(SPAN_CALL.search(ln) for ln in lines[start:end + 1])
+    assert any('span("oracle.timed")' in ln for ln in lines[:start])
