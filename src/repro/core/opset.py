@@ -16,14 +16,25 @@ Taint-driven input generation (§5.2): MODEL_CONFIG dims stay fixed,
 NUM_TOKS / NUM_REQS dims are substituted per sweep point, MIX dims are
 recalculated from H with the workload component replaced, untainted dims
 are kept.
+
+Operands are drawn without a program per shape (``generate_array``): a
+profiling pass draws hundreds of them in some sixty shapes, and an eager
+RNG program for each shape would be traced, lowered and loaded anew in
+every fresh process.  Floating values come from a seeded host pool and
+reach the device by ``jax.device_put``; only operands too large for a
+host copy and transfer to beat one program are drawn on the device.
 """
 from __future__ import annotations
 
+import functools
+import math
+import operator
 from dataclasses import dataclass, field
-from typing import Any, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax._src import core as jcore
 
 from repro.core.callgraph import Node, build_hierarchy, collapse
@@ -79,15 +90,85 @@ def resize_shape(shape: Sequence[int], taints: Sequence[Taint], *,
                  for s, t in zip(shape, taints))
 
 
+# ---------------------------------------------------------------------------
+# operand drawing
+# ---------------------------------------------------------------------------
+
+#: floating operands are N(0, 1) x OPERAND_SCALE, cast to their dtype
+OPERAND_SCALE = 0.02
+#: values in the host pool; keys below it start distinct windows
+_POOL_LEN = 1 << 19
+#: odd, so ``key * _POOL_STRIDE`` is a bijection on keys mod _POOL_LEN
+_POOL_STRIDE = 0x9E3779B1 % _POOL_LEN
+#: floating operands larger than this are drawn on the device by one fused
+#: program per shape: above it, tiling a window of the pool to the
+#: operand's size and transferring it costs more than the program's first
+#: call (on a TPU v5e both took ~85 ms at 64 MiB; PERF.md)
+DEVICE_DRAW_BYTES = 64 << 20
+
+# dtype -> the pool in that dtype, twice over, read-only: every window of
+# up to _POOL_LEN values is one contiguous slice.  It holds values, never
+# an operand, and drawing it costs under 1% of a profiling pass.
+_POOLS: Dict[np.dtype, np.ndarray] = {}
+# running totals of generate_array's draws per path (``operand_counts``)
+_COUNTS = {"host_arrays": 0, "host_bytes": 0, "device_arrays": 0}
+
+
+def _pool(dt: np.dtype) -> np.ndarray:
+    pool = _POOLS.get(dt)
+    if pool is None:
+        f32 = np.dtype(np.float32)
+        if dt == f32:
+            z = np.random.default_rng(0).standard_normal(_POOL_LEN, f32)
+            pool = np.concatenate([z, z]) * f32.type(OPERAND_SCALE)
+        else:
+            pool = _pool(f32).astype(dt)
+        pool.flags.writeable = False
+        _POOLS[dt] = pool
+    return pool
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _draw_on_device(key, shape, dtype):
+    z = jax.random.normal(jax.random.key(key), shape, jnp.float32)
+    return (z * OPERAND_SCALE).astype(dtype)
+
+
 def generate_array(shape, dtype, key=None) -> jax.Array:
+    """One operand on the default device.  Integers are zeros (valid
+    indices everywhere), booleans ones, floating values N(0, 1) x
+    OPERAND_SCALE in ``dtype``.  ``key`` (an int, default 0) picks the
+    values: the same shape, dtype and key give the same array.
+
+    Below DEVICE_DRAW_BYTES no program runs: the values are a window of a
+    host pool, tiled to the operand's size, placed by ``jax.device_put``.
+    Above it, one fused program per shape draws them on the device."""
     dt = jnp.dtype(dtype)
+    shape = tuple(int(s) for s in shape)
+    key = 0 if key is None else operator.index(key)
+    n = math.prod(shape)
     if dt.kind in "iu":
-        return jnp.zeros(shape, dt)              # valid indices everywhere
-    if dt.kind == "b":
-        return jnp.ones(shape, dt)
-    if key is None:
-        key = jax.random.key(0)
-    return jax.random.normal(key, shape, jnp.float32).astype(dt) * 0.02
+        host = np.zeros(shape, dt)
+    elif dt.kind == "b":
+        host = np.ones(shape, dt)
+    elif n * dt.itemsize > DEVICE_DRAW_BYTES:
+        _COUNTS["device_arrays"] += 1
+        return _draw_on_device(key, shape, dt)
+    else:
+        start = key * _POOL_STRIDE % _POOL_LEN
+        window = _pool(dt)[start:start + min(n, _POOL_LEN)]
+        host = (window if n <= _POOL_LEN else np.resize(window, n)
+                ).reshape(shape)
+    _COUNTS["host_arrays"] += 1
+    _COUNTS["host_bytes"] += host.nbytes
+    return jax.device_put(host)
+
+
+def operand_counts() -> Dict[str, int]:
+    """Running totals of ``generate_array``'s draws in this process:
+    ``host_arrays`` and ``host_bytes`` placed from the host,
+    ``device_arrays`` drawn by a program on the device."""
+    return dict(_COUNTS)
 
 
 def generate_inputs(op: TraceOp, *, toks: Optional[int] = None,
@@ -96,7 +177,7 @@ def generate_inputs(op: TraceOp, *, toks: Optional[int] = None,
     for i, (shape, dtype, taints) in enumerate(
             zip(op.in_shapes, op.in_dtypes, op.in_taints)):
         rs = resize_shape(shape, taints, toks=toks, reqs=reqs)
-        out.append(generate_array(rs, dtype, jax.random.key(i + 1)))
+        out.append(generate_array(rs, dtype, i + 1))
     return out
 
 
@@ -251,7 +332,7 @@ class ModuleEntry:
             # taints for free vars: find the producing/consuming TraceOp
             shape = tuple(getattr(v.aval, "shape", ()))
             dtype = getattr(v.aval, "dtype", jnp.float32)
-            args.append(generate_array(shape, dtype, jax.random.key(i + 1)))
+            args.append(generate_array(shape, dtype, i + 1))
         return jcore.eval_jaxpr(jaxpr, [], *args)
 
 

@@ -39,18 +39,19 @@ measurement bulk path.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import re
 from concurrent.futures import ProcessPoolExecutor
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 from repro.configs.base import ModelConfig
 from repro.core import backends as oracles
 from repro.core.database import LatencyDB
 from repro.core.latency_model import nearest_point_scale
 from repro.core.opset import (ModuleEntry, OpEntry, detach_op_entry,
-                              find_runnable_set)
+                              find_runnable_set, operand_counts)
 from repro.core.runner import ModelTrace, trace_model
 from repro.core.signature import (Signature, module_entry_signature,
                                   op_entry_signature)
@@ -61,6 +62,18 @@ from repro.serving.context import (ModuleContext, cached_build_context,
 
 def _module_of(entry) -> str:
     return entry.module
+
+
+@contextlib.contextmanager
+def _drawing_operands() -> Iterator[None]:
+    """The ``profile.operands`` span, with the operands drawn inside it on
+    each path (``opset.operand_counts``) as its arguments."""
+    before = operand_counts()
+    with span("profile.operands") as s:
+        yield
+        s.set_metadata(**{k: v - before[k]
+                          for k, v in operand_counts().items()})
+
 
 REPEATS = 100           # measurements per sweep point in a real profiler
 
@@ -587,14 +600,14 @@ class DoolyProf:
 
     def _measure_op(self, entry: OpEntry, toks, reqs) -> float:
         # jit_callable draws the operator's operands (and binds it)
-        with span("profile.operands"):
+        with _drawing_operands():
             fn, args = entry.jit_callable(toks=toks, reqs=reqs)
         return self.validation.check(
             lambda: oracles.measure(self.oracle, fn, args),
             f"op {entry.kind} toks={toks} reqs={reqs}")
 
     def _measure_module(self, mc: ModuleContext, toks, reqs, ctx) -> float:
-        with span("profile.operands"):
+        with _drawing_operands():
             args = mc.abstract_inputs(max(toks, 1), max(reqs, 1),
                                       max(ctx, 1))
             full = (mc.params,) + tuple(args)

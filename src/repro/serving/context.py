@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.core.opset import generate_array
 from repro.models import attention as attn_mod
 from repro.models import mamba as mamba_mod
 from repro.models import mla as mla_mod
@@ -44,16 +45,11 @@ class ModuleContext:
     def abstract_inputs(self, toks: int, reqs: int, ctx: int):
         return self.input_spec(toks, reqs, ctx)
 
-    def materialize(self, tree: Tree, key: Optional[jax.Array] = None):
-        key = key if key is not None else jax.random.key(0)
-
-        def gen(sds):
-            dt = jnp.dtype(sds.dtype)
-            if dt.kind in "iu":
-                return jnp.zeros(sds.shape, dt)
-            return (jax.random.normal(key, sds.shape, jnp.float32) * 0.02
-                    ).astype(dt)
-        return jax.tree.map(gen, tree)
+    def materialize(self, tree: Tree, key: Optional[int] = None):
+        """``tree``'s ShapeDtypeStructs as arrays on the device, each
+        drawn by ``opset.generate_array`` with ``key``."""
+        return jax.tree.map(
+            lambda sds: generate_array(sds.shape, sds.dtype, key), tree)
 
 
 def _sds(shape, dtype):

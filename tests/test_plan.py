@@ -88,6 +88,28 @@ def test_plan_build_is_pure_and_deterministic(corpus, traces):
     assert p1.models == p2.models
 
 
+# The plan of this corpus before operands were drawn from a host pool:
+# values, not shapes, changed, so signatures (HLO op sets) and the plan's
+# id must not, or latency DBs profiled earlier would stop deduplicating.
+RECORDED_PLAN_ID = "0249151af8cb9865"
+RECORDED_TASK_HASHES = (
+    "8d3968fc0cddc8bb", "000d241d8f1a7a5b", "f18f81b19ac8ba89",
+    "dac197ed0edfd432", "e7eb36a749e4ff13", "8fecc7dd3358c3c7",
+    "80cdfb37f84f98c1", "12cc35243b0b3068", "6e68a3ee6fe33d9f",
+    "e6966edba444ec38", "e77c503a05c73de0", "1dde03b375c02776",
+    "bea80a9bd5874733", "319f50424d77e58f", "5c30fcaa889450be",
+    "2e99a9605e9f9fc6", "426a62270e33d51d", "72b3bb24486dfd2b",
+    "52aefe612e579e36")
+
+
+def test_plan_id_and_task_signatures_match_recorded(corpus, traces):
+    with LatencyDB() as db:
+        plan = _plan(db, corpus, traces)
+    assert tuple(t.sig_hash[:16] for t in plan.tasks) == \
+        RECORDED_TASK_HASHES
+    assert plan.plan_id == RECORDED_PLAN_ID
+
+
 def test_overlapping_corpus_dedups_at_least_30pct(executed_state):
     _, cov, _, _, _ = executed_state
     assert cov.naive_tasks > cov.plan_tasks
