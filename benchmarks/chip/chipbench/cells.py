@@ -3,7 +3,12 @@
 A cell is one entry of ``workloads`` in ``BENCHMARK.json``.  Its files:
 
 - ``configs/<config>.json``: the model as it is run (zoo name, overrides,
-  engine settings, the limits of the ``correct`` comparison);
+  engine settings, the limits of the ``correct`` comparison, the cut that
+  the CPU tests run under ``smoke``);
+- ``configs/<config>.py``: what the harness computes of that architecture,
+  a function ``arch(config)`` that returns a ``reference.Arch`` (its
+  float32 layer, the operations of a token, the profiler's modules and
+  their reference);
 - ``traffic/<traffic>.json``: the driver (``serve`` or ``profile``) and the
   generator's parameters;
 - ``metrics/<metric>.py``: one reader per per-layer metric, a function
@@ -14,6 +19,7 @@ from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional
@@ -40,10 +46,17 @@ class Cell:
     traffic: Dict[str, Any]
     end_to_end: tuple
     per_layer: tuple
+    model: Any          # the module ``configs/<config>.py``
 
     @property
     def driver(self) -> str:
         return self.traffic["driver"]
+
+    @property
+    def arch(self):
+        """The configuration's ``reference.Arch``, built from
+        ``config``."""
+        return self.model.arch(self.config)
 
 
 def _metric(entry: Dict[str, Any]) -> Metric:
@@ -90,19 +103,37 @@ def find_cell(bench: Dict[str, Any], name: str,
                                  / f"{w['config']}.json"),
                 traffic=load_json(bench_dir / "traffic"
                                   / f"{w['traffic']}.json"),
-                end_to_end=e2e, per_layer=per)
+                end_to_end=e2e, per_layer=per,
+                model=config_module(w["config"], bench_dir))
+
+
+def _load(path: Path, prefix: str, name: str):
+    spec = importlib.util.spec_from_file_location(
+        prefix + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod        # dataclasses look their module up
+    spec.loader.exec_module(mod)
+    return mod
+
+
+_CONFIG_MODULES: Dict[Path, Any] = {}
+
+
+def config_module(name: str, bench_dir: Path = BENCH_DIR):
+    """The module ``configs/<name>.py``, loaded once per process, so that
+    the ``Arch`` class it defines (a static argument of jitted functions)
+    stays one class."""
+    path = (bench_dir / "configs" / f"{name}.py").resolve()
+    if path not in _CONFIG_MODULES:
+        _CONFIG_MODULES[path] = _load(path, "chipbench_config_", name)
+    return _CONFIG_MODULES[path]
 
 
 def metric_reader(name: str, bench_dir: Path = BENCH_DIR
                   ) -> Callable[[Any], Optional[float]]:
     """The ``read`` function of ``metrics/<name>.py``."""
-    path = bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"),
-        path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(bench_dir / "metrics" / f"{name}.py", "chipbench_metric_",
+                 name).read
 
 
 def read_per_layer(metrics: List[Metric], run,
@@ -119,10 +150,15 @@ def read_per_layer(metrics: List[Metric], run,
 
 def model_config(config: Dict[str, Any]):
     """The program's ``ModelConfig`` for a configuration file: the zoo entry
-    with the file's overrides applied."""
+    with the file's overrides applied (an object given as a JSON object
+    replaces the fields it names)."""
+    import dataclasses
     from repro.configs import get_config
-    from repro.configs.base import MLAConfig
-    over = dict(config.get("overrides", {}))
-    if "mla" in over:
-        over["mla"] = MLAConfig(**over["mla"])
-    return get_config(config["zoo"]).with_overrides(**over)
+    base = get_config(config["zoo"])
+    over = {}
+    for key, value in config.get("overrides", {}).items():
+        current = getattr(base, key)
+        over[key] = (dataclasses.replace(current, **value)
+                     if isinstance(value, dict)
+                     and dataclasses.is_dataclass(current) else value)
+    return base.with_overrides(**over)

@@ -21,6 +21,7 @@ reference.
 from __future__ import annotations
 
 import math
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Set, Tuple
@@ -34,6 +35,13 @@ from chipbench.cells import Cell, model_config
 from chipbench.trace import span
 
 ORACLE = "cpu_wallclock"
+#: device bytes the sampled calls may hold between them: a tied 256k
+#: vocabulary table is 2 GB, and more of them held through the window
+#: would crowd the profiler's own operands
+SAMPLE_BYTES = 4 << 30
+#: elements of a reference's output (and of an operand it lowers) taken
+#: on the host at once
+BLOCK = 1 << 25
 
 
 @dataclass
@@ -91,15 +99,21 @@ def one_pass(plan, hardware: str, sweep) -> Pass:
 class CallSample:
     """A seeded reservoir sample of the oracle's timed calls, per kind
     (module contexts, single operators), held for the check; and the
-    operand shapes of every timed call, per pass."""
+    operand shapes of every timed call, per pass.  Each call goes with the
+    window of the plan's task that made it (0 for an operator): the
+    module's layer window, which its reference applies.  A call whose
+    operands would take the sample past ``SAMPLE_BYTES`` is passed over
+    (the seed's draws stay the same)."""
 
     def __init__(self, seed: int, sizes: Dict[str, int]):
         self.rng = np.random.default_rng([int(seed), 3])
         self.sizes = sizes
         self.kept: Dict[str, List] = {k: [] for k in sizes}
         self.seen: Dict[str, int] = {k: 0 for k in sizes}
-        self.shapes: List[List[Tuple]] = []     # per pass, per call
+        self.nbytes = 0
+        self.shapes: List[List[Tuple]] = []     # per pass: (shapes, window)
         self.traced = False         # mark each timed call in the trace
+        self.window = 0             # of the task being measured
         self._orig = None
 
     def new_pass(self):
@@ -108,34 +122,51 @@ class CallSample:
     def offer(self, fn, args):
         kind = "module" if isinstance(args[0], dict) else "op"
         if self.shapes:
-            self.shapes[-1].append(call_shapes(args))
+            self.shapes[-1].append((call_shapes(args), self.window))
         self.seen[kind] += 1
         keep, n = self.kept[kind], self.seen[kind]
+        nbytes = sum(math.prod(np.shape(x)) * np.dtype(x.dtype).itemsize
+                     for x in jax.tree_util.tree_leaves(args))
+        call = (fn, args, self.window, nbytes)
         if len(keep) < self.sizes[kind]:
-            keep.append((fn, args))
+            if self.nbytes + nbytes <= SAMPLE_BYTES:
+                keep.append(call)
+                self.nbytes += nbytes
         else:
             j = int(self.rng.integers(0, n))
-            if j < len(keep):
-                keep[j] = (fn, args)
+            if j < len(keep) \
+                    and self.nbytes - keep[j][3] + nbytes <= SAMPLE_BYTES:
+                self.nbytes += nbytes - keep[j][3]
+                keep[j] = call
 
     def __enter__(self):
         from repro.core import backends
+        from repro.core.profiler import DoolyProf
         self._orig = orig = backends.ORACLES[ORACLE]
+        self._orig_rows = orig_rows = DoolyProf.measure_payload_rows
 
         def timed(fn, args, **kw):
             self.offer(fn, args)
             with span(self.traced, "oracle"):
                 return orig(fn, args, **kw)
+
+        def rows(prof, payload, *a, **kw):
+            self.window = payload[2] if payload[0] == "module" else 0
+            return orig_rows(prof, payload, *a, **kw)
         backends.ORACLES[ORACLE] = timed
+        DoolyProf.measure_payload_rows = rows
         return self
 
     def __exit__(self, *exc):
         from repro.core import backends
+        from repro.core.profiler import DoolyProf
         backends.ORACLES[ORACLE] = self._orig
+        DoolyProf.measure_payload_rows = self._orig_rows
         return False
 
     def calls(self):
-        return [c for k in sorted(self.kept) for c in self.kept[k]]
+        """(fn, args, window) of each kept call."""
+        return [c[:3] for k in sorted(self.kept) for c in self.kept[k]]
 
 
 # ---------------------------------------------------------------------------
@@ -153,65 +184,36 @@ def call_shapes(args) -> Tuple:
     return ("op", (), tuple(tuple(np.shape(x)) for x in args))
 
 
-def mla_param_shapes(a: reference.Arch) -> Tuple:
-    """The MLA module's weights at the configuration's widths, in
-    :func:`call_shapes`' form."""
-    h, qk = a.n_heads, a.qk_nope + a.qk_rope
-    return tuple(sorted({
-        "wdq/w": (a.d_model, a.q_lora_rank),
-        "q_norm/scale": (a.q_lora_rank,),
-        "wuq/w": (a.q_lora_rank, h * qk),
-        "wdkv/w": (a.d_model, a.kv_lora_rank + a.qk_rope),
-        "kv_norm/scale": (a.kv_lora_rank,),
-        "wuk/w": (a.kv_lora_rank, h * a.qk_nope),
-        "wuv/w": (a.kv_lora_rank, h * a.v_head),
-        "o/w": (h * a.v_head, a.d_model),
-    }.items()))
-
-
-def planned_points(plan, sweep) -> Set[Tuple]:
+def planned_points(a: reference.Arch, plan, sweep) -> Set[Tuple]:
     """The sweep points a pass has to time, as far as operand shapes show
-    them: an MLA prefill's shapes carry no context, an operator's none but
-    its tokens and requests."""
+    them: a module's as its configuration states them, an operator's
+    by its tokens and requests."""
     kinds = {t.payload[0] for t in plan.todo}
     points: Set[Tuple] = set()
     if "module" in kinds:
-        points |= {("prefill", t, r) for t in sweep.toks for r in sweep.reqs}
-        points |= {("decode", r, c) for r in sweep.reqs for c in sweep.ctx}
+        points |= a.module_points(sweep)
     if kinds - {"module"}:
         points |= {("op", t, r) for t, r in sweep.op_points}
     return points
 
 
-def call_point(a: reference.Arch, sweep, shapes: Tuple
+def call_point(a: reference.Arch, sweep, shapes: Tuple, window: int = 0
                ) -> Tuple[bool, Optional[Tuple]]:
     """(on the plan, point shown): whether a timed call's operands have
     the configuration's widths and the sizes of one of the sweep's points,
-    and which point, where the shapes show one."""
+    and which point, where the shapes show one.  ``window``: that of the
+    call's task, one of the configuration's layer windows for a module."""
     kind, params, ins = shapes
     if kind == "module":
-        if a.attention != "mla" or params != mla_param_shapes(a):
-            return False, None
-        d = a.d_model
-        if len(ins) == 2:
-            (r, t, dx), pos = ins
-            ok = (dx == d and pos == (r, t) and t in sweep.toks
-                  and r in sweep.reqs)
-            return ok, ("prefill", t, r) if ok else None
-        if len(ins) == 4:
-            (r, one, dx), c, kr, n = ins
-            ctx = c[1] if len(c) == 3 else None
-            ok = (one == 1 and dx == d and c == (r, ctx, a.kv_lora_rank)
-                  and kr == (r, ctx, a.qk_rope) and n == (r,)
-                  and r in sweep.reqs and ctx in sweep.ctx)
-            return ok, ("decode", r, ctx) if ok else None
-        return False, None
+        return a.module_point(sweep, params, ins, window)
+    widths = {1} | a.op_widths()
+    if all(x in widths for s in ins for x in s):
+        return True, None               # weights alone: no point shown
     main = max(ins, key=len)
     if len(main) < 2:
-        widths = {1, a.d_model, a.d_ff, a.vocab}
-        return all(x in widths for s in ins for x in s), None
+        return False, None
     r, t = main[:2]
-    widths = {1, r, t, a.d_model, a.d_ff, a.vocab}
+    widths = {1, r, t} | a.op_widths()
     ok = ((t, r) in {tuple(p) for p in sweep.op_points}
           and all(x in widths for s in ins for x in s))
     return ok, ("op", t, r) if ok else None
@@ -221,12 +223,12 @@ def shape_checks(a: reference.Arch, plan, sweep,
                  passes: List[List[Tuple]]) -> Dict[str, int]:
     """Calls whose operands fit no planned point at the configuration's
     widths, and planned points that a pass did not time."""
-    planned = planned_points(plan, sweep)
+    planned = planned_points(a, plan, sweep)
     off = missing = 0
     for calls in passes:
         seen = set()
-        for shapes in calls:
-            ok, point = call_point(a, sweep, shapes)
+        for shapes, window in calls:
+            ok, point = call_point(a, sweep, shapes, window)
             off += not ok
             if point is not None:
                 seen.add(point)
@@ -239,7 +241,52 @@ def shape_checks(a: reference.Arch, plan, sweep,
 # ---------------------------------------------------------------------------
 
 def _np(x) -> np.ndarray:
-    return np.asarray(jnp.asarray(x, jnp.float32), np.float64)
+    """``x`` in float64 on the host (converted there, with no copy on the
+    device)."""
+    return np.asarray(x).astype(np.float64)
+
+
+def _blocks(x: np.ndarray) -> List:
+    """Index of ``x`` block by block of its first axis, each of at most
+    ``BLOCK`` elements (one block for a smaller array)."""
+    if x.ndim == 0 or x.size <= BLOCK:
+        return [Ellipsis]
+    rows = max(1, BLOCK // (x.size // x.shape[0]))
+    return [slice(s, s + rows) for s in range(0, x.shape[0], rows)]
+
+
+def _lowered(x: np.ndarray, low: str) -> np.ndarray:
+    """``x`` (float64) rounded as ``low`` stores it, scaled along its last
+    axis, in float64."""
+    out = np.empty(np.shape(x), np.float64)
+    for b in _blocks(out):
+        out[b] = np.asarray(reference.quantize(
+            jnp.asarray(x[b], jnp.float32), low, -1), np.float64)
+    return out
+
+
+def rel_error(got, want: np.ndarray) -> float:
+    """Relative L2 error of ``got`` against ``want`` (float64) over the
+    entries where ``want`` is finite (e.g. not rsqrt of a negative input),
+    block by block, so that an output the size of a vocabulary table is
+    never copied whole; infinite where ``got`` is not finite there."""
+    got = np.asarray(got)
+    if not want.flags.c_contiguous and want.T.flags.c_contiguous:
+        got, want = got.T, want.T       # a transpose's: walk it in order
+    want_norms, diff_norms = [], []
+    for b in _blocks(want):
+        w, g = want[b], _np(got[b])
+        norm = float(np.linalg.norm(w))
+        if not math.isfinite(norm):
+            ok = np.isfinite(w)
+            w, g = w[ok], g[ok]
+            norm = float(np.linalg.norm(w))
+        diff = float(np.linalg.norm(g - w))
+        if not math.isfinite(diff):
+            return float("inf")
+        want_norms.append(norm)
+        diff_norms.append(diff)
+    return math.hypot(*diff_norms) / max(math.hypot(*want_norms), 1e-30)
 
 
 def _dot_general(x, y, dimension_numbers):
@@ -258,7 +305,8 @@ def _dot_general(x, y, dimension_numbers):
     out = ([xs[i] for i in lb] + [xs[i] for i in range(x.ndim)
                                   if i not in lb and i not in lc]
            + [ys[j] for j in range(y.ndim) if j not in rb and j not in rc])
-    return np.einsum(f"{''.join(xs)},{''.join(ys)}->{''.join(out)}", x, y)
+    return np.einsum(f"{''.join(xs)},{''.join(ys)}->{''.join(out)}", x, y,
+                     optimize=True)
 
 
 def _broadcast_in_dim(x, shape, broadcast_dimensions):
@@ -305,43 +353,18 @@ def op_reference(eqn, inputs: List[np.ndarray]) -> Optional[np.ndarray]:
     return None
 
 
-def module_reference(a: reference.Arch, args) -> Optional[jax.Array]:
-    """The MLA attention module contexts the profiler times, row by row;
-    None for another module."""
-    p, x = args[0], args[1]
-    if "wdq" not in p:
-        return None
-    rows = []
-    for b in range(x.shape[0]):
-        xb = x[b].astype(jnp.float32)
-        if len(args) == 3:                         # full sequence
-            rows.append(reference.attention_block(a, p, xb, args[2][b], 0,
-                                                  None))
-        else:                                      # one-token decode
-            c, kr, n = args[2][b], args[3][b], args[4][b]
-            pos = n[None]
-            q = reference.mla_q(a, p, xb, pos, None)
-            c_new, kr_new = reference.mla_latent(a, p, xb, pos, None)
-            c_all = c.astype(jnp.float32).at[n].set(c_new[0])
-            kr_all = kr.astype(jnp.float32).at[n].set(kr_new[0])
-            k, v = reference.mla_kv(a, p, c_all, kr_all, None)
-            kpos = jnp.arange(c.shape[0])
-            o = reference.attend(q, k, v, pos, kpos, kpos <= n, 0)
-            rows.append(reference.mm(o.reshape(1, -1), p["o"]["w"]))
-    return jnp.stack(rows)
-
-
-def call_error(a: reference.Arch, fn, args,
+def call_error(a: reference.Arch, fn, args, window: int = 0,
                control: Optional[Dict[str, str]] = None
                ) -> Optional[Tuple[str, float]]:
     """(precision class, relative L2 error) of one timed call against the
-    reference: the program's output, or with ``control`` (precision class
-    -> lower precision), the reference computed on lowered operands."""
+    reference (a module's by its configuration, at the ``window`` of its
+    task): the program's output, or with ``control`` (precision class ->
+    lower precision), the reference computed on lowered operands."""
     got = jax.jit(fn)(*args)
     cls = "bf16" if got.dtype == jnp.bfloat16 else "f32"
     low = control.get(cls) if control else None
     if isinstance(args[0], dict):
-        want = module_reference(a, args)
+        want = a.module_reference(args, window)
         if want is None:
             return None
         want = _np(want)
@@ -349,7 +372,7 @@ def call_error(a: reference.Arch, fn, args,
             lowered = jax.tree.map(
                 lambda t: reference.quantize(t.astype(jnp.float32), low, -1)
                 if jnp.issubdtype(t.dtype, jnp.floating) else t, args)
-            got = module_reference(a, lowered)
+            got = a.module_reference(lowered, window)
     else:
         eqns = jax.make_jaxpr(fn)(*args).jaxpr.eqns
         if len(eqns) != 1:
@@ -359,17 +382,10 @@ def call_error(a: reference.Arch, fn, args,
         if want is None:
             return None
         if low:
-            lowered = [_np(reference.quantize(jnp.asarray(x, jnp.float32),
-                                              low, -1)) for x in inputs]
-            got = jnp.asarray(op_reference(eqns[0], lowered)
-                              ).astype(got.dtype)
-    got = _np(got)
-    ok = np.isfinite(want)          # e.g. rsqrt of the negative inputs
-    if not np.all(np.isfinite(got[ok])):
-        return cls, float("inf")
-    norm = float(np.linalg.norm(want[ok]))
-    err = float(np.linalg.norm(got[ok] - want[ok])) / max(norm, 1e-30)
-    return cls, err
+            got = np.asarray(op_reference(
+                eqns[0], [_lowered(x, low) for x in inputs])
+            ).astype(np.float32).astype(got.dtype)
+    return cls, rel_error(got, want)
 
 
 # ---------------------------------------------------------------------------
@@ -413,7 +429,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     cfg = model_config(cell.config)
     sweep = sweep_config(cell.traffic)
     hardware = jax.devices()[0].device_kind
-    run = ProfileRun(cell, reference.Arch.from_config(cell.config))
+    run = ProfileRun(cell, cell.arch)
     t0 = time.perf_counter()
     with ProfileStore(":memory:", hardware=hardware, oracle=ORACLE,
                       sweep=sweep) as store:
@@ -443,6 +459,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
                 break
     run.window_s = time.perf_counter() - t0
     run.window = (t0, t0 + run.window_s)
+    print("[chipbench] passes_s=" + ",".join(
+        f"{p.t_end - p.t_start:.3f}" for p in run.passes), file=sys.stderr)
     run.trace_events = recorder.events() if recorder is not None else None
     run.attempted = sum(p.report.measured + p.report.quarantined
                         for p in run.passes)
@@ -454,11 +472,15 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     acc.update(shape_checks(run.arch, plan, sweep, sample.shapes))
     errs: Dict[str, float] = {"bf16": 0.0, "f32": 0.0}
     compared = 0
-    for fn, args in sample.calls():
-        out = call_error(run.arch, fn, args)
+    secs = []
+    for fn, args, window in sample.calls():
+        t_call = time.perf_counter()
+        out = call_error(run.arch, fn, args, window)
+        secs.append(f"{call_shapes(args)[0]}:{time.perf_counter() - t_call:.1f}")
         if out is not None:
             errs[out[0]] = max(errs[out[0]], out[1])
             compared += 1
+    print("[chipbench] check_call_s=" + ",".join(secs), file=sys.stderr)
     run.checks = {k: {"value": v, "limit": 0} for k, v in acc.items()}
     run.checks.update({
         f"op_err_{k}": {"value": v, "limit": limits[f"op_err_{k}"]}

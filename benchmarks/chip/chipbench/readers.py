@@ -56,3 +56,12 @@ def compile_share(run) -> Optional[float]:
     w0, w1 = run.window
     secs = sum(d for _, d, t in events if w0 <= t <= w1)
     return 100.0 * secs / run.window_s
+
+
+def of_trace(reader):
+    """A metric reader that applies ``reader`` (a ``chipbench.spans``
+    reader) to a traced run's events; None for an untraced run."""
+    def read(run) -> Optional[float]:
+        events = getattr(run, "trace_events", None)
+        return reader(events) if events else None
+    return read

@@ -1,4 +1,5 @@
-"""Plain float32 reference of the configurations the benchmark serves.
+"""Plain float32 reference of the configurations the benchmark serves:
+the parts they share and the loops over layers.
 
 Written from the configuration file's sizes alone: it imports nothing of
 the program and computes every matrix product at ``Precision.HIGHEST``
@@ -6,11 +7,12 @@ the program and computes every matrix product at ``Precision.HIGHEST``
 the benchmark's own (``weights.make_params``), addressed by the names of
 the program's parameter layout.
 
-The block is the one the configuration file states: pre-norm RMSNorm,
-attention, residual, RMSNorm, SwiGLU, residual; rotary embedding over
-split halves; GQA with a sliding window on the layers the pattern marks,
-or MLA (low-rank query, normed latent, one shared rotary key).  Where that
-departs from the published model, the file lists it under ``assumed``.
+Each configuration's ``configs/<name>.py`` subclasses :class:`Arch` and
+composes the parts below (norms, rotary embedding over split halves,
+masked attention, SwiGLU, GQA and MLA projections) into the block the
+configuration file states and into the references of the module calls
+the profiler times.  Where that departs from the published model, the
+file lists it under ``assumed``.
 
 ``quant`` computes every matrix product on operands rounded to a lower
 precision (``bf16``, or ``fp8`` e4m3 with per-row or per-column scales):
@@ -21,7 +23,7 @@ from __future__ import annotations
 import functools
 import math
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,53 +37,69 @@ SEQ_BUCKET = 512       # sequences are padded to a multiple of this
 
 @dataclass(frozen=True)
 class Arch:
-    attention: str            # "gqa" | "mla"
+    """What the shared loops and drivers ask of a configuration.
+
+    Each ``configs/<name>.py`` builds a subclass of it from the
+    configuration file (its function ``arch(config)``): the sizes below,
+    the sizes of its own layers, and the methods that answer every
+    architecture-specific question of the harness."""
     d_model: int
     n_layers: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
     vocab: int
     eps: float
-    rope_theta: float
-    window: int               # sliding window of the windowed layers
-    window_pattern: int       # every k-th layer global (0: none windowed)
     tie_embeddings: bool
-    q_lora_rank: int = 0
-    kv_lora_rank: int = 0
-    qk_nope: int = 0
-    qk_rope: int = 0
-    v_head: int = 0
 
-    @classmethod
-    def from_config(cls, c: Dict[str, Any]) -> "Arch":
-        mla = c.get("attention") == "mla"
-        return cls(
-            attention="mla" if mla else "gqa",
-            d_model=c["hidden_size"], n_layers=c["num_hidden_layers"],
-            n_heads=c["num_attention_heads"],
-            n_kv_heads=c["num_key_value_heads"],
-            head_dim=c.get("head_dim")
-            or c["hidden_size"] // c["num_attention_heads"],
-            d_ff=c["intermediate_size"], vocab=c["vocab_size"],
-            eps=c["rms_norm_eps"], rope_theta=c["rope_theta"],
-            window=c.get("sliding_window") or 0,
-            window_pattern=c.get("sliding_window_pattern") or 0,
-            tie_embeddings=c["tie_word_embeddings"],
-            q_lora_rank=c.get("q_lora_rank", 0),
-            kv_lora_rank=c.get("kv_lora_rank", 0),
-            qk_nope=c.get("qk_nope_head_dim", 0),
-            qk_rope=c.get("qk_rope_head_dim", 0),
-            v_head=c.get("v_head_dim", 0))
+    # the float32 forward (``forward_hidden``, ``served_gaps``)
+    def layer(self, lp, x: jax.Array, i: int,
+              quant: Optional[str] = None) -> jax.Array:
+        """Layer ``i`` of one sequence ``x`` (S, d_model), float32 at
+        HIGHEST, with every product on ``quant`` operands."""
+        raise NotImplementedError
 
-    def layer_window(self, i: int) -> int:
-        """Window of layer ``i``: every ``window_pattern``-th layer is
-        global, the rest windowed."""
-        if not self.window or not self.window_pattern:
-            return 0
-        return 0 if i % self.window_pattern == self.window_pattern - 1 \
-            else self.window
+    # operations (``chipbench.flops``)
+    def layer_weights(self, i: int) -> int:
+        """Weights one token multiplies through in layer ``i``."""
+        raise NotImplementedError
+
+    def layer_attention(self, i: int, ctx) -> int:
+        """Operations of layer ``i``'s attention for one query over
+        ``ctx`` positions (itself included), summed over ``ctx`` when it
+        is an array of them."""
+        raise NotImplementedError
+
+    # the profile driver (``chipbench.profile``)
+    def module_params(self) -> Tuple:
+        """The parameter shapes of the modules the profiler may time,
+        sorted ((path, shape), ...), as ``profile.call_shapes`` gives
+        them."""
+        raise NotImplementedError
+
+    def module_points(self, sweep) -> Set[Tuple]:
+        """The sweep points the module calls of one pass show."""
+        raise NotImplementedError
+
+    def module_point(self, sweep, params: Tuple, ins: Tuple, window: int
+                     ) -> Tuple[bool, Optional[Tuple]]:
+        """(on the plan, point shown) of one timed module call from its
+        parameter and input shapes, and the window of its task."""
+        raise NotImplementedError
+
+    def op_widths(self) -> Set[int]:
+        """The widths an operator's operands may take besides 1 and the
+        point's tokens and requests."""
+        raise NotImplementedError
+
+    def module_reference(self, args, window: int) -> Optional[jax.Array]:
+        """A timed module call recomputed in float32 at HIGHEST; None for
+        a module it does not cover."""
+        raise NotImplementedError
+
+
+def arch_sizes(c: Dict[str, Any]) -> Dict[str, Any]:
+    """The base :class:`Arch` fields of a configuration file."""
+    return dict(d_model=c["hidden_size"], n_layers=c["num_hidden_layers"],
+                vocab=c["vocab_size"], eps=c["rms_norm_eps"],
+                tie_embeddings=c["tie_word_embeddings"])
 
 
 # ---------------------------------------------------------------------------
@@ -156,8 +174,18 @@ def swiglu(p, x, quant):
     return mm(jax.nn.silu(g) * u, p["down"]["w"], quant)
 
 
+def prenorm_block(lp, x, attention: Callable, eps: float, quant):
+    """Pre-norm residual block: RMSNorm, ``attention(h)``, residual,
+    RMSNorm, SwiGLU, residual."""
+    h = rmsnorm(x, lp["ln1"]["scale"], eps)
+    x = x + attention(h)
+    h2 = rmsnorm(x, lp["ln2"]["scale"], eps)
+    return x + swiglu(lp["ffn"], h2, quant)
+
+
 # ---------------------------------------------------------------------------
-# attention modules (also the references of the profiled module calls)
+# attention parts (the configurations compose them into layers and into the
+# references of the profiled module calls)
 # ---------------------------------------------------------------------------
 
 def gqa_qkv(a: Arch, p, h, pos, quant):
@@ -196,33 +224,9 @@ def mla_kv(a: Arch, p, c, kr, quant):
     return k, v
 
 
-def attention_block(a: Arch, p, h, pos, window, quant):
-    """Self-attention of one sequence over itself (causal)."""
-    s = h.shape[0]
-    valid = jnp.ones((s,), bool)
-    if a.attention == "mla":
-        q = mla_q(a, p, h, pos, quant)
-        c, kr = mla_latent(a, p, h, pos, quant)
-        k, v = mla_kv(a, p, c, kr, quant)
-        o = attend(q, k, v, jnp.arange(s), jnp.arange(s), valid, 0)
-        return mm(o.reshape(s, -1), p["o"]["w"], quant)
-    q, k, v = gqa_qkv(a, p, h, pos, quant)
-    o = attend(q, k, v, jnp.arange(s), jnp.arange(s), valid, window)
-    return mm(o.reshape(s, -1), p["o"]["w"], quant)
-
-
 # ---------------------------------------------------------------------------
 # whole model, layer by layer
 # ---------------------------------------------------------------------------
-
-@functools.partial(jax.jit, static_argnames=("a", "window", "quant"))
-def layer(lp, x, *, a: Arch, window: int, quant: Optional[str]):
-    pos = jnp.arange(x.shape[0])
-    h = rmsnorm(x, lp["ln1"]["scale"], a.eps)
-    x = x + attention_block(a, lp["attn"], h, pos, window, quant)
-    h2 = rmsnorm(x, lp["ln2"]["scale"], a.eps)
-    return x + swiglu(lp["ffn"], h2, quant)
-
 
 @functools.partial(jax.jit, static_argnames=("a", "quant"))
 def head(params, x, *, a: Arch, quant: Optional[str]):
@@ -264,8 +268,7 @@ def forward_hidden(a: Arch, params, seqs: Sequence[np.ndarray],
         xs.append(jnp.take(table, jnp.asarray(padded), axis=0).astype(F32))
     for i in range(a.n_layers):
         lp = layer_params(params, i)
-        xs = [layer(lp, x, a=a, window=a.layer_window(i), quant=quant)
-              for x in xs]
+        xs = [a.layer(lp, x, i, quant) for x in xs]
     return xs
 
 

@@ -297,8 +297,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     ``report.setup_done()`` is called when the window opens and
     ``report.memory_peak()`` before the program's state is freed."""
     eng, cap, reqs = setup(cell, seed)
-    run = ServeRun(cell, reference.Arch.from_config(cell.config),
-                   report.peak_flops)
+    run = ServeRun(cell, cell.arch, report.peak_flops)
     run.setup_s = report.setup_done()
     recorder = None
     if traced:

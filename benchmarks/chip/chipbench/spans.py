@@ -2,20 +2,15 @@
 
 The program marks its host work with ``dooly.*`` spans
 (``repro.runtime.span``): the engine's iteration and its parts, the
-profiler's tasks and the oracle's calls.  ``load_events`` reads them from
-an xplane file beside what ``trace.load_events`` reads, under two more
-keys:
+profiler's tasks and the oracle's calls.  ``trace.load_events`` reads them
+under ``"program"`` (each name cut at the ``#`` that opens its arguments),
+and the device's whole-program events (line ``XLA Modules``, named
+``jit_<function>``) under ``"modules"``.
 
-- ``"program"``: ``[[name, start_ns, dur_ns], ...]``, the ``dooly.*`` host
-  spans, each name cut at the ``#`` that opens a span's arguments;
-- ``"modules"``: ``[[plane, name, start_ns, dur_ns], ...]``, the device's
-  whole-program events (line ``XLA Modules``), named ``jit_<function>``.
-
-``Recorder`` is ``trace.Recorder`` with these events.  The readers below
-take such events and return None where the trace holds no program span
-(or, for a program's device time, no module event), so that a program
-without spans reports nothing rather than a 0.  All of them read the
-traced window (the host span ``chipbench.window``) only.
+The readers below take such events and return None where the trace holds
+no program span (or, for a program's device time, no module event), so
+that a program without spans reports nothing rather than a 0.  All of them
+read the traced window (the host span ``chipbench.window``) only.
 """
 from __future__ import annotations
 
@@ -25,65 +20,20 @@ import numpy as np
 
 from chipbench import trace
 
-PROGRAM_PREFIX = "dooly."
-MODULE_LINES = ("XLA Modules",)
-
-
-def _base_name(name: str) -> str:
-    return name.split("#", 1)[0]
-
-
-def load_events(path: str) -> Dict[str, List]:
-    """``trace.load_events(path)`` plus ``"program"`` and ``"modules"``."""
-    from jax.profiler import ProfileData
-    events = trace.load_events(path)
-    program, modules = [], []
-    for plane in ProfileData.from_file(path).planes:
-        if plane.name.startswith(trace.DEVICE_PREFIX):
-            for line in plane.lines:
-                if line.name in MODULE_LINES:
-                    modules.extend([plane.name, e.name, int(e.start_ns),
-                                    int(e.duration_ns)] for e in line.events)
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                program.extend([_base_name(e.name), int(e.start_ns),
-                                int(e.duration_ns)] for e in line.events
-                               if e.name.startswith(PROGRAM_PREFIX))
-    events.update(program=program, modules=modules)
-    return events
-
-
-class Recorder(trace.Recorder):
-    """``trace.Recorder`` whose events carry the program's spans and the
-    device's module events."""
-
-    def events(self) -> Dict[str, List]:
-        import glob
-        import os
-        import shutil
-        try:
-            paths = glob.glob(os.path.join(self.logdir, "**",
-                                           "*.xplane.pb"), recursive=True)
-            if not paths:
-                raise RuntimeError("the profiler wrote no trace")
-            return load_events(paths[0])
-        finally:
-            shutil.rmtree(self.logdir, ignore_errors=True)
-
 
 def execute_traced(cell, seed: int, seconds: float, **kw):
-    """``harness.execute(cell, seed, seconds, True, **kw)`` with this
-    module's ``Recorder`` in ``trace.Recorder``'s place (``serve.run_cell``
-    and ``profile.run_cell`` look it up when they run).  Returns (result, events); the events also hold
-    ``"compiles"``: ``[[event, seconds, end_ns], ...]``, JAX's
-    compile-pipeline events that end inside the traced window, on the
+    """``harness.execute(cell, seed, seconds, True, **kw)``, keeping the
+    trace's events (``serve.run_cell`` and ``profile.run_cell`` look
+    ``trace.Recorder`` up when they run).  Returns (result, events); the
+    events also hold ``"compiles"``: ``[[event, seconds, end_ns], ...]``,
+    JAX's compile-pipeline events that end inside the traced window, on the
     trace's clock."""
     import time
     import jax
     from chipbench import harness
     kept, compiles = [], []
 
-    class Keeping(Recorder):
+    class Keeping(trace.Recorder):
         def start(self):
             super().start()
             self.t_start = time.perf_counter()
@@ -124,18 +74,10 @@ def _program(events, name: Optional[str] = None) -> List[List]:
     """Program spans named ``dooly.<name>`` (all, without a name) that lie
     inside the traced window."""
     w0, w1 = _window(events)
-    full = None if name is None else PROGRAM_PREFIX + name
+    full = None if name is None else trace.PROGRAM_PREFIX + name
     return [e for e in events.get("program", ())
             if (full is None or e[0] == full)
             and e[1] >= w0 and e[1] + e[2] <= w1]
-
-
-def _joined(events) -> Dict[str, List]:
-    """The events with the program's spans among the benchmark's host
-    spans, renamed into their prefix, for ``trace``'s labelling."""
-    return dict(events, host=events["host"] + [
-        [trace.SPAN_PREFIX + n[len(PROGRAM_PREFIX):], s, d]
-        for n, s, d in events["program"]])
 
 
 def idle_gaps(events, top: int = 10) -> Optional[List]:
@@ -145,7 +87,7 @@ def idle_gaps(events, top: int = 10) -> Optional[List]:
     without program spans."""
     if not events.get("program"):
         return None
-    return trace.reduce_events(_joined(events), top=top)["idle_gaps"]
+    return trace.reduce_events(events, top=top)["idle_gaps"]
 
 
 def compile_by_span(events) -> Optional[Dict[str, float]]:
@@ -154,7 +96,7 @@ def compile_by_span(events) -> Optional[Dict[str, float]]:
     None without program spans or compile events."""
     if not events.get("program") or not events.get("compiles"):
         return None
-    spans = [h for h in _joined(events)["host"] if h[0] != trace.WINDOW_SPAN]
+    spans = trace.host_spans(events)
     out: Dict[str, float] = {}
     for _, secs, t in events["compiles"]:
         label = trace._label(spans, t)
@@ -191,7 +133,8 @@ def program_device_ms(events, function: str) -> Optional[float]:
     w0, w1 = _window(events)
     full = "jit_" + function
     ds = [d for _, name, s, d in events.get("modules", ())
-          if _base_name(name).split("(", 1)[0] == full and w0 <= s < w1]
+          if trace.base_name(name).split("(", 1)[0] == full
+          and w0 <= s < w1]
     return float(np.median(ds)) / 1e6 if ds else None
 
 

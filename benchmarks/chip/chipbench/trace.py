@@ -1,15 +1,18 @@
 """From a profiler trace to device busy time, top device ops and idle gaps.
 
 ``Recorder`` runs ``jax.profiler`` over part of a window into a temporary
-directory and returns the trace as plain events; ``reduce_events`` is the
-reduction the per-layer metrics and the breakdown read:
+directory and returns the trace as plain events (``load_events``): the
+device's op and whole-program events, the benchmark's ``chipbench.*``
+host spans and the program's own ``dooly.*`` spans.  ``reduce_events`` is
+the reduction the per-layer metrics and the breakdown read:
 
 - the traced window is the host span ``chipbench.window`` that the driver
   opens around the traced part of its measured window;
 - busy time is the union of the intervals of the device planes' op events
   (line ``XLA Ops``) inside that window, averaged over the chips used;
-- idle gaps are the rest of the window, each named by the innermost
-  ``chipbench.*`` host span around its midpoint (what the host was doing).
+- idle gaps are the rest of the window, each named by the innermost host
+  span around its midpoint, the benchmark's or the program's (what the
+  host was doing).
 """
 from __future__ import annotations
 
@@ -22,8 +25,10 @@ from typing import Any, Dict, List, Tuple
 
 WINDOW_SPAN = "chipbench.window"
 SPAN_PREFIX = "chipbench."
+PROGRAM_PREFIX = "dooly."
 DEVICE_PREFIX = "/device:"
 OP_LINES = ("XLA Ops",)
+MODULE_LINES = ("XLA Modules",)
 #: an op's name is its HLO instruction text; the breakdown keeps its head
 NAME_CHARS = 120
 
@@ -67,25 +72,44 @@ class Recorder:
             shutil.rmtree(self.logdir, ignore_errors=True)
 
 
+def base_name(name: str) -> str:
+    """A span's name without the ``#`` that opens its arguments."""
+    return name.split("#", 1)[0]
+
+
 def load_events(path: str) -> Dict[str, List]:
-    """Device op events and ``chipbench.*`` host spans of an xplane file,
-    as ``{"device": [[plane, name, start_ns, dur_ns], ...],
-    "host": [[name, start_ns, dur_ns], ...]}``."""
+    """The events of an xplane file:
+
+    - ``"device"``: ``[[plane, name, start_ns, dur_ns], ...]``, the device
+      planes' op events (line ``XLA Ops``);
+    - ``"modules"``: the same, of their whole-program events (line ``XLA
+      Modules``, named ``jit_<function>``);
+    - ``"host"``: ``[[name, start_ns, dur_ns], ...]``, the ``chipbench.*``
+      host spans;
+    - ``"program"``: the same, of the program's ``dooly.*`` spans, each
+      name cut at the ``#`` that opens its arguments."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    device, host = [], []
+    device, modules, host, program = [], [], [], []
     for plane in pd.planes:
         if plane.name.startswith(DEVICE_PREFIX):
             for line in plane.lines:
-                if line.name in OP_LINES:
-                    device.extend([plane.name, e.name, int(e.start_ns),
-                                   int(e.duration_ns)] for e in line.events)
+                into = (device if line.name in OP_LINES else
+                        modules if line.name in MODULE_LINES else None)
+                if into is not None:
+                    into.extend([plane.name, e.name, int(e.start_ns),
+                                 int(e.duration_ns)] for e in line.events)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                host.extend([e.name, int(e.start_ns), int(e.duration_ns)]
-                            for e in line.events
-                            if e.name.startswith(SPAN_PREFIX))
-    return {"device": device, "host": host}
+                for e in line.events:
+                    span = [e.name, int(e.start_ns), int(e.duration_ns)]
+                    if e.name.startswith(SPAN_PREFIX):
+                        host.append(span)
+                    elif e.name.startswith(PROGRAM_PREFIX):
+                        span[0] = base_name(e.name)
+                        program.append(span)
+    return {"device": device, "modules": modules, "host": host,
+            "program": program}
 
 
 def _union(intervals: List[Tuple[int, int]]) -> List[Tuple[int, int]]:
@@ -99,13 +123,26 @@ def _union(intervals: List[Tuple[int, int]]) -> List[Tuple[int, int]]:
 
 
 def _label(spans, t: int) -> str:
-    """Innermost (shortest) host span that covers instant ``t``."""
+    """Innermost (shortest) host span that covers instant ``t``, the
+    benchmark's or the program's, without its prefix and arguments."""
     best = None
     for name, s, d in spans:
         if s <= t < s + d and name != WINDOW_SPAN \
                 and (best is None or d < best[1]):
             best = (name, d)
-    return best[0][len(SPAN_PREFIX):] if best else "none"
+    if best is None:
+        return "none"
+    name = base_name(best[0])
+    for prefix in (SPAN_PREFIX, PROGRAM_PREFIX):
+        if name.startswith(prefix):
+            return name[len(prefix):]
+    return name
+
+
+def host_spans(events) -> List:
+    """The benchmark's host spans but its window, and the program's."""
+    return ([h for h in events["host"] if h[0] != WINDOW_SPAN]
+            + list(events.get("program", ())))
 
 
 def reduce_events(events: Dict[str, List], chips: int = 1,
@@ -133,7 +170,7 @@ def reduce_events(events: Dict[str, List], chips: int = 1,
                     if edges[i + 1] > edges[i])
     n = max(chips, 1)
     gaps.sort(key=lambda g: g[0] - g[1])
-    spans = [h for h in events["host"] if h[0] != WINDOW_SPAN]
+    spans = host_spans(events)
     return {
         "busy_s": busy_ns / n / 1e9,
         "window_s": (w1 - w0) / 1e9,

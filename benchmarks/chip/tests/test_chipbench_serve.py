@@ -20,8 +20,11 @@ def test_traced_serve_run_reports_per_layer_metrics():
     cell = smoke_cell("minicpm3-4b.chat")
     res = run(cell, seconds=3.0, traced=True)
     assert res["correct"], res["checks"]
-    # the CPU has no device plane: the idle share is left out, not 0
-    want = {m.name for m in cell.per_layer} - {"device_idle_share.chat"}
+    # the CPU has no device plane: the idle share and the decode
+    # program's device time are left out, not 0
+    want = {m.name for m in cell.per_layer} - {
+        "device_idle_share.chat", "engine.decode_device_ms.chat"}
+    assert res["metrics"]["engine.host_ms_per_iter.chat"]["value"] > 0
     assert set(res["metrics"]) == want
     assert {"busy_s", "window_s"} <= set(res["device"])
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}

@@ -62,7 +62,7 @@ def test_engine_execute_spans_cover_the_iteration():
     eng._decode_fn = counted(eng._decode_fn)
     eng._chunk_fns = {b: counted(f) for b, f in eng._chunk_fns.items()}
     _serve(eng, [40, 20, 9], calls)                 # every eager op once
-    rec = spans.Recorder()
+    rec = trace.Recorder()
     rec.start()
     _serve(eng, [45, 30, 12, 5], calls)
     rec.stop()
@@ -122,7 +122,7 @@ def test_profile_execute_spans_each_oracle_call(monkeypatch):
             n_calls.append(1)
             return orig(fn, args, **kw)
         monkeypatch.setitem(backends.ORACLES, "cpu_wallclock", counted)
-        rec = spans.Recorder()
+        rec = trace.Recorder()
         rec.start()
         rep = store.execute(plan)
         rec.stop()
@@ -225,7 +225,10 @@ def test_gaps_are_named_by_the_innermost_program_span():
     assert gaps[2] == ["engine.prefill_chunk", pytest.approx(0.011)]
     # [0, 6) ms: mid 3, in the first iteration's inputs
     assert gaps[3] == ["engine.inputs", pytest.approx(0.006)]
-    # the benchmark's own reduction still names them by its spans
+    # the harness's own reduction names them alike
+    assert trace.reduce_events(ev, top=4)["idle_gaps"] == gaps
+    # without the program's spans, by the benchmark's alone
+    ev["program"] = []
     assert [g[0] for g in trace.reduce_events(ev, top=4)["idle_gaps"]] == \
         ["execute"] * 4
 
@@ -251,7 +254,8 @@ def test_readers_give_none_without_program_spans(reader):
     ev = _hand_events()
     ev["program"], ev["modules"] = [], []
     assert reader(ev) is None
-    # a trace read by ``trace.load_events`` has neither key
+    # events without either key (a trace recorded before the program had
+    # spans)
     del ev["program"], ev["modules"]
     assert reader(ev) is None
     assert spans.report(ev, "serve") == {}
@@ -272,3 +276,48 @@ def test_trace_recorded_on_the_chip_names_the_programs():
     assert spans.host_ms_per_iter(ev) > 0
     gaps = spans.idle_gaps(ev)
     assert gaps and all(g[0].startswith("engine.") for g in gaps)
+
+
+def test_idle_share_of_the_chip_trace_is_the_same_with_program_spans():
+    """The harness reads the program's spans since they name idle gaps;
+    busy time, the window and so every ``device_idle_share.*`` reading
+    are what the benchmark's spans alone gave."""
+    from chipbench import readers
+    if not SAMPLE.is_file():
+        pytest.fail(f"missing {SAMPLE}")
+    ev = json.loads(SAMPLE.read_text())
+    before = dict(ev, program=[], modules=[])
+    r0, r1 = trace.reduce_events(before), trace.reduce_events(ev)
+    assert (r0["busy_s"], r0["window_s"]) == (r1["busy_s"], r1["window_s"])
+    assert r0["device_ops"] == r1["device_ops"]
+
+    class Run:
+        pass
+    shares = []
+    for r in (r0, r1):
+        run = Run()
+        run.trace = r
+        shares.append(readers.idle_share(run))
+    assert shares[0] == shares[1] and 0 < shares[0] < 100
+    assert {g[0] for g in r0["idle_gaps"]} <= {"execute", "none"}
+    assert all(g[0].startswith("engine.") for g in r1["idle_gaps"])
+
+
+@pytest.mark.parametrize("name,kind,field", [
+    ("profile.timed_share", "profile", "profile.timed_share"),
+    ("profile.operand_share", "profile", "profile.operand_share"),
+    ("engine.host_ms_per_iter.chat", "serve", "engine.host_ms_per_iter"),
+    ("engine.host_ms_per_iter.batch", "serve", "engine.host_ms_per_iter"),
+    ("engine.decode_device_ms.chat", "serve", "engine.decode_device_ms"),
+    ("engine.decode_device_ms.batch", "serve", "engine.decode_device_ms")])
+def test_span_metric_files_read_the_spans_readers(name, kind, field):
+    """Each span metric of ``BENCHMARK.json`` is its ``chipbench.spans``
+    reader on a traced run's events, and nothing on an untraced run."""
+    from chipbench import cells
+
+    class Run:
+        trace_events = _hand_events()
+    read = cells.metric_reader(name)
+    assert read(Run()) == spans.READERS[kind][field](Run.trace_events)
+    Run.trace_events = None
+    assert read(Run()) is None

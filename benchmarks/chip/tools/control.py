@@ -33,7 +33,7 @@ def serve_control(cell, seeds, precision, candidates):
     from chipbench.cells import model_config
     from chipbench.serve import _to_request, decode_inputs, sample
     cfg = model_config(cell.config)
-    a = reference.Arch.from_config(cell.config)
+    a = cell.arch
     for seed in seeds:
         t0 = time.time()
         items = traffic.requests(cell.traffic["params"], seed,
@@ -69,7 +69,7 @@ def profile_control(cell, seeds, precision):
     with ProfileStore(":memory:", hardware=hw, oracle=P.ORACLE,
                       sweep=sweep) as store:
         plan = store.plan([cfg], backends=("xla",))
-    a = P.reference.Arch.from_config(cell.config)
+    a = cell.arch
     low = {"bf16": precision, "f32": "bf16"}
     for seed in seeds:
         sample = P.CallSample(seed, dict(cell.traffic["check_calls"]))
@@ -78,9 +78,9 @@ def profile_control(cell, seeds, precision):
             P.one_pass(plan, hw, sweep)
         errs = {"bf16": 0.0, "f32": 0.0}
         prog = {"bf16": 0.0, "f32": 0.0}
-        for fn, args in sample.calls():
-            c = P.call_error(a, fn, args, control=low)
-            g = P.call_error(a, fn, args)
+        for fn, args, window in sample.calls():
+            c = P.call_error(a, fn, args, window, control=low)
+            g = P.call_error(a, fn, args, window)
             if c is not None:
                 errs[c[0]] = max(errs[c[0]], c[1])
                 prog[g[0]] = max(prog[g[0]], g[1])

@@ -5,9 +5,9 @@ the device's module events, and write its events for the tests.
 
 Builds ``serving.Engine`` for minicpm3-4b at published widths with two
 layers and four short cache rows, serves a warm-up burst, then serves
-four requests under ``spans.Recorder``.  Prints every plane and line of
+four requests under ``trace.Recorder``.  Prints every plane and line of
 the raw trace with its event count and first event names, writes the
-extracted events (``spans.load_events``' form, op names cut to
+extracted events (``trace.load_events``' form, op names cut to
 ``trace.NAME_CHARS``) to ``<out.json>``, and prints ``spans.report``.
 """
 import json
@@ -44,7 +44,7 @@ def main(out):
         max_num_seqs=4, max_batch_tokens=128, chunk_size=64,
         prefix_caching=False))
     serve(eng, [100, 70, 40, 20], 3)          # every eager op runs once
-    rec = spans.Recorder()
+    rec = trace.Recorder()
     rec.start()
     serve(eng, [90, 60, 30, 12], 3)
     rec.stop()
