@@ -156,10 +156,8 @@ class PlanBackend:
             n_dec = len(plan.decodes)
         else:
             lengths, n_dec = plan
-        if self.cfg.ssm_state <= 0:
-            lengths = tuple(bucket_chunk(length,
-                                         self.sched_config.chunk_size)
-                            for length in lengths)
+        lengths = tuple(bucket_chunk(length, self.sched_config.chunk_size)
+                        for length in lengths)
         return lengths, bool(n_dec)
 
     def _cached_points(self, keys: List[PointKey]) -> None:
@@ -209,8 +207,7 @@ class PlanBackend:
         self._sync_cache()
         total = 0.0
         for length, start in rec.chunks:
-            c = length if self.cfg.ssm_state > 0 else bucket_chunk(
-                length, self.sched_config.chunk_size)
+            c = bucket_chunk(length, self.sched_config.chunk_size)
             self._cached_points([("prefill", c, 1, self.max_seq)])
             total += self._point_cache[("prefill", c, 1, self.max_seq)]
         if rec.n_decodes:

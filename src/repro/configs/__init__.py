@@ -24,10 +24,11 @@ _MODULES = {
     "falcon-mamba-7b": "falcon_mamba_7b",
     "llama3-8b": "llama3_8b",
     "command-r7b": "command_r7b",
+    "jamba2-3b": "jamba2_3b",
 }
 
 ASSIGNED_ARCHS = tuple(list(_MODULES)[:10])
-CORPUS_ARCHS = tuple(_MODULES)          # 12-model corpus for §7.2
+CORPUS_ARCHS = tuple(list(_MODULES)[:12])   # 12-model corpus for §7.2
 
 
 def _load(name: str):

@@ -81,6 +81,10 @@ class ModelConfig:
     ssm_conv: int = 4
     ssm_expand: int = 2
     ssm_dt_rank: int = 0                  # 0 -> d_model // 16
+    ssm_ffn: bool = False                 # an FFN after the mixer on Mamba layers
+    ssm_dt_norms: bool = False            # RMSNorm on dt, B and C before dt_proj
+    attn_layer_period: int = 0            # ssm family: layer i attends where
+    attn_layer_offset: int = 0            # i % period == offset (0: none)
 
     # encoder-decoder
     n_enc_layers: int = 0                 # >0 => enc-dec; n_layers = decoder layers
@@ -130,11 +134,17 @@ class ModelConfig:
     def resolved_dt_rank(self) -> int:
         return self.ssm_dt_rank or max(1, self.d_model // 16)
 
+    def layer_attends(self, i: int) -> bool:
+        """An ``ssm`` stack's layer ``i`` is an attention layer where
+        ``i % attn_layer_period == attn_layer_offset`` (Jamba's placement)."""
+        return (self.attn_layer_period > 0
+                and i % self.attn_layer_period == self.attn_layer_offset)
+
     def layer_kinds(self) -> Tuple[str, ...]:
         """Per-layer block kind for the decoder stack."""
         kinds = []
         for i in range(self.n_layers):
-            if self.family == "ssm":
+            if self.family == "ssm" and not self.layer_attends(i):
                 kinds.append("mamba")
             elif self.family == "hybrid":
                 kinds.append("hybrid")
@@ -178,6 +188,8 @@ class ModelConfig:
             di, st, dtr = self.ssm_d_inner, self.ssm_state, self.resolved_dt_rank
             mamba = (2 * d * di + di * self.ssm_conv + di * (dtr + 2 * st)
                      + dtr * di + di * st + di + di * d)
+            if self.ssm_dt_norms:
+                mamba += dtr + 2 * st
 
         def ffn(dff):
             # silu -> SwiGLU (gate, up, down); gelu -> classic MLP (up, down)
@@ -187,7 +199,7 @@ class ModelConfig:
         for i, kind in enumerate(self.layer_kinds()):
             p = 2 * d  # two norms
             if kind == "mamba":
-                p += mamba
+                p += mamba + (ffn(self.d_ff) if self.ssm_ffn else 0)
             elif kind == "hybrid":
                 p += attn + mamba + ffn(self.d_ff)
             elif kind == "moe":

@@ -119,5 +119,6 @@ def mamba_scan(x, dt, A, Bc, Cc, D, h0=None, *, block_d: int = 0,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="mamba_scan",
     )(x, dt, Bc, Cc, A.T, D.reshape(1, di), h0.transpose(0, 2, 1))
     return y[:, :s], h.transpose(0, 2, 1)

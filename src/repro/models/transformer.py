@@ -72,6 +72,9 @@ def block_spec(cfg: ModelConfig, desc: BlockDesc) -> Tree:
     spec: Dict[str, Tree] = {"ln1": rmsnorm_spec(d)}
     if desc.kind == "mamba":
         spec["mamba"] = mamba_mod.mamba_spec(cfg)
+        if cfg.ssm_ffn:
+            spec["ln2"] = rmsnorm_spec(d)
+            spec["ffn"] = mlp_spec(d, cfg.d_ff, cfg.act)
         return spec
     if cfg.attn_type == "mla":
         spec["attn"] = mla_mod.mla_spec(cfg)
@@ -111,8 +114,7 @@ def block_apply(p: Tree, x: jax.Array, cfg: ModelConfig, desc: BlockDesc, *,
             cache = {"conv": tail, "h": hstate}
         else:
             y = mamba_mod.mamba_mixer(p["mamba"], h, cfg)
-        x = x + y
-        return x, aux, (cache or None)
+        return _mamba_ffn(p, x + y, cfg), aux, (cache or None)
 
     # attention (+ parallel mamba for hybrid)
     if cfg.attn_type == "mla":
@@ -156,6 +158,24 @@ def block_apply(p: Tree, x: jax.Array, cfg: ModelConfig, desc: BlockDesc, *,
     return x, aux, (cache or None)
 
 
+def _mamba_ffn(p: Tree, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """A Mamba block's FFN after its mixer (Jamba), where it has one."""
+    if not cfg.ssm_ffn:
+        return x
+    return x + mlp(p["ffn"], rmsnorm(p["ln2"], x, cfg.norm_eps), cfg.act)
+
+
+def _keep_inactive(new: Tree, old: Tree, active: Optional[jax.Array]
+                   ) -> Tree:
+    """Recurrent state of the rows in ``active`` (B,) from ``new``, of the
+    rest from ``old``."""
+    if active is None:
+        return new
+    return jax.tree.map(
+        lambda n, o: jnp.where(active.reshape((-1,) + (1,) * (n.ndim - 1)),
+                               n, o), new, old)
+
+
 def _fill_ring(kv: jax.Array, slots: int) -> jax.Array:
     """(B,S,KV,D) -> ring cache (B,slots,KV,D): last min(S,slots) rows at
     slot = pos % slots."""
@@ -191,8 +211,10 @@ def _write_chunk(cache: jax.Array, new: jax.Array, lengths: jax.Array
 
 def block_prefill_chunk(p: Tree, x: jax.Array, cache: Tree, cfg: ModelConfig,
                         desc: BlockDesc, *, lengths: jax.Array, impl: str,
-                        enc_kv=None) -> Tuple[jax.Array, Tree]:
-    """x: (B,C,D) chunk; lengths (B,): tokens already cached per row.
+                        enc_kv=None, n_valid: Optional[jax.Array] = None
+                        ) -> Tuple[jax.Array, Tree]:
+    """x: (B,C,D) chunk; lengths (B,): tokens already cached per row;
+    n_valid (B,): the chunk's real tokens per row, the rest bucket padding.
     Engine caches are absolute-position (use_ring=False)."""
     from repro.kernels import ref as kref
     b, c, _ = x.shape
@@ -203,8 +225,8 @@ def block_prefill_chunk(p: Tree, x: jax.Array, cache: Tree, cfg: ModelConfig,
     if desc.kind == "mamba":
         y, (tail, hs) = mamba_mod.mamba_mixer(
             p["mamba"], h, cfg, h0=cache["h"],
-            conv_tail=cache["conv"], return_state=True)
-        return x + y, {"conv": tail, "h": hs}
+            conv_tail=cache["conv"], return_state=True, n_valid=n_valid)
+        return _mamba_ffn(p, x + y, cfg), {"conv": tail, "h": hs}
 
     if cfg.attn_type == "mla":
         m = cfg.mla
@@ -248,7 +270,7 @@ def block_prefill_chunk(p: Tree, x: jax.Array, cache: Tree, cfg: ModelConfig,
     if desc.kind == "hybrid":
         ym, (tail, hs) = mamba_mod.mamba_mixer(
             p["mamba"], h, cfg, h0=cache["h"],
-            conv_tail=cache["conv"], return_state=True)
+            conv_tail=cache["conv"], return_state=True, n_valid=n_valid)
         y = y + ym
         new_cache.update({"conv": tail, "h": hs})
     x = x + y
@@ -274,13 +296,17 @@ def block_prefill_chunk(p: Tree, x: jax.Array, cache: Tree, cfg: ModelConfig,
 def block_decode(p: Tree, x: jax.Array, cache: Tree, cfg: ModelConfig,
                  desc: BlockDesc, *, lengths: jax.Array, impl: str,
                  enc_kv: Optional[Tuple[jax.Array, jax.Array]] = None,
-                 kv_seq_shards: int = 1) -> Tuple[jax.Array, Tree]:
+                 kv_seq_shards: int = 1,
+                 active: Optional[jax.Array] = None) -> Tuple[jax.Array, Tree]:
+    """active (B,): the rows whose recurrent state the step advances (all
+    when None); the others keep theirs, as a row between prefill chunks
+    must."""
     new_cache: Dict[str, jax.Array] = {}
     h = rmsnorm(p["ln1"], x, cfg.norm_eps)
 
     if desc.kind == "mamba":
         y, st = mamba_mod.mamba_step(p["mamba"], h, cache, cfg)
-        return x + y, st
+        return _mamba_ffn(p, x + y, cfg), _keep_inactive(st, cache, active)
 
     if cfg.attn_type == "mla":
         y, nc = mla_mod.mla_decode(p["attn"], h, cache, cfg, lengths=lengths)
@@ -292,10 +318,10 @@ def block_decode(p: Tree, x: jax.Array, cache: Tree, cfg: ModelConfig,
             kv_seq_shards=kv_seq_shards)
         new_cache.update(nc)
     if desc.kind == "hybrid":
-        ym, st = mamba_mod.mamba_step(
-            p["mamba"], h, {"conv": cache["conv"], "h": cache["h"]}, cfg)
+        old = {"conv": cache["conv"], "h": cache["h"]}
+        ym, st = mamba_mod.mamba_step(p["mamba"], h, old, cfg)
         y = y + ym
-        new_cache.update(st)
+        new_cache.update(_keep_inactive(st, old, active))
     x = x + y
 
     if desc.cross:

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeSpec
 from repro.models import attention as attn_mod
+from repro.models import mamba as mamba_mod
 from repro.models import transformer as tfm
 from repro.models.layers import (ParamSpec, abstract_params, axes_tree,
                                  embedding, embedding_spec, init_params,
@@ -234,8 +235,12 @@ class Model:
 
     def decode_step(self, params, cache, tokens: jax.Array,
                     lengths: jax.Array, *, impl: str = "auto",
-                    kv_seq_shards: int = 1) -> Tuple[jax.Array, Tree]:
-        """tokens (B,) or (B,1); lengths (B,) = context size so far."""
+                    kv_seq_shards: int = 1,
+                    active: Optional[jax.Array] = None
+                    ) -> Tuple[jax.Array, Tree]:
+        """tokens (B,) or (B,1); lengths (B,) = context size so far;
+        active (B,) bool: the rows that decode, whose recurrent state the
+        step advances (all rows when None)."""
         cfg = self.cfg
         if tokens.ndim == 1:
             tokens = tokens[:, None]
@@ -254,7 +259,8 @@ class Model:
                     x, nc = tfm.block_decode(slices[j], x, caches[j], cfg,
                                              desc, lengths=lengths, impl=impl,
                                              enc_kv=enc_kv,
-                                             kv_seq_shards=kv_seq_shards)
+                                             kv_seq_shards=kv_seq_shards,
+                                             active=active)
                     if desc.cross:
                         nc = dict(nc)
                         nc["enc_k"], nc["enc_v"] = enc_kv
@@ -296,6 +302,15 @@ class Model:
             lambda s: jnp.zeros(s.shape, s.dtype),
             self.cache_spec(batch, max_seq, enc_len, use_ring=use_ring))
 
+    def reset_recurrent(self, cache: Tree) -> Tree:
+        """``cache`` with its recurrent state (each Mamba layer's conv tail
+        and SSM state) zeroed and its KV cache as it was: what a row holds
+        before a new request's first chunk."""
+        state = mamba_mod.init_mamba_state(self.cfg, 1, jnp.float32)
+        return {"blocks": [{k: jnp.zeros_like(v) if k in state else v
+                            for k, v in block.items()}
+                           for block in cache["blocks"]]}
+
     # ------------------------------------------------------------------
     # chunked prefill (serving engine path; caches are absolute-position)
     # ------------------------------------------------------------------
@@ -307,8 +322,11 @@ class Model:
         """tokens (B,C): next C prompt tokens per row; lengths (B,): tokens
         already cached.  Returns (logits at ``last_pos`` (default: the
         chunk's last position) (B,V), updated cache).  last_pos (B,) indexes
-        within the chunk — used when the engine pads chunks to size buckets."""
+        within the chunk — used when the engine pads chunks to size buckets:
+        the positions after it are padding, which leaves recurrent state as
+        the real tokens left it."""
         cfg = self.cfg
+        n_valid = None if last_pos is None else last_pos + 1
         x = embedding(params["embed"], tokens)
         x = constrain(x, "batch", None, None)
         pattern = self.pattern
@@ -323,7 +341,7 @@ class Model:
                         enc_kv = (caches[j]["enc_k"], caches[j]["enc_v"])
                     x, nc = tfm.block_prefill_chunk(
                         slices[j], x, caches[j], cfg, desc, lengths=lengths,
-                        impl=impl, enc_kv=enc_kv)
+                        impl=impl, enc_kv=enc_kv, n_valid=n_valid)
                     if desc.cross:
                         nc = dict(nc)
                         nc["enc_k"], nc["enc_v"] = enc_kv

@@ -2,10 +2,12 @@
 
 TPU-style static-shape engine: one padded cache of ``max_num_seqs`` rows is
 allocated up front (absolute-position slots, no ring); decode runs the full
-row batch every iteration (inactive rows masked by lengths), prefill chunks
-run per-row through ``Model.prefill_chunk``.  Fixed shapes mean exactly two
-compiled programs per (chunk size), which is the bucketing discipline real
-TPU serving stacks (JetStream-style) use.
+row batch every iteration (inactive rows masked by lengths, their recurrent
+state held), prefill chunks run per-row through ``Model.prefill_chunk``,
+padded to a power-of-two bucket.  Fixed shapes mean one decode program and
+one program per chunk bucket, which is the bucketing discipline real TPU
+serving stacks (JetStream-style) use.  A row's recurrent state (Mamba
+layers) is zeroed when a new request takes the row.
 
 The engine clock advances by *measured model time* per iteration, so a
 trace replay is reproducible and directly comparable with DoolySim (which
@@ -19,6 +21,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import build_model
@@ -71,12 +74,15 @@ class Engine:
         self.clock = 0.0
         self.records: List[IterationRecord] = []
 
-        def decode_step(p, c, t, l):
-            return self.model.decode_step(p, c, t, l, impl=impl)
+        def decode_step(p, c, t, l, active=None):
+            return self.model.decode_step(p, c, t, l, impl=impl,
+                                          active=active)
 
         # named functions: the device trace shows ``jit_decode_step``
         self._decode_fn = jax.jit(decode_step)
         self._chunk_fns: Dict[int, Any] = {}
+        self._reset_fn = (jax.jit(self.model.reset_recurrent)
+                          if cfg.ssm_state > 0 else None)
         self.warmup()
 
     # ------------------------------------------------------------------
@@ -95,11 +101,14 @@ class Engine:
         r = self.sched.config.max_num_seqs
         toks = jnp.zeros((r,), jnp.int32)
         jax.block_until_ready(
-            self._decode_fn(self.params, self.cache, toks, self.lengths)[0])
+            self._decode_fn(self.params, self.cache, toks, self.lengths,
+                            jnp.zeros((r,), bool))[0])
         b = 8
         while b <= self.sched.config.chunk_size:
             fn = self._chunk_fn(b)
             row = self._row_cache(0)
+            if self._reset_fn is not None:
+                row = self._reset_fn(row)
             out = fn(self.params, row, jnp.zeros((1, b), jnp.int32),
                      jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32))
             jax.block_until_ready(out[0])
@@ -120,22 +129,22 @@ class Engine:
         """Run one iteration plan; returns measured model seconds.
 
         Host spans (:func:`repro.runtime.span`) split the iteration in a
-        ``jax.profiler`` trace: per chunk ``engine.prefill_chunk``, then
+        ``jax.profiler`` trace: per chunk ``engine.prefill_chunk`` (with
+        its ``bucket`` and the ``pad`` tokens that fill it), then
         ``engine.decode``, each made of ``engine.inputs``,
         ``engine.dispatch`` (the step program's call), ``engine.sync``
         (waiting on the device), and ``engine.write_row`` /
-        ``engine.readback`` (host work on the outputs)."""
+        ``engine.readback`` (host work on the outputs).  A request's first
+        chunk on a model with recurrent state first zeroes the row's state
+        (``engine.state_reset``)."""
         with span("engine.execute"):
             t0 = time.perf_counter()
             new_tokens: Dict[int, int] = {}
             for chunk in plan.prefills:
                 r = chunk.req
-                with span("engine.prefill_chunk", rid=r.rid):
-                    # SSM state is sequential: pad tokens would corrupt
-                    # it, so mamba/hybrid archs run exact-length chunks
-                    b = chunk.length if self.cfg.ssm_state > 0 else \
-                        bucket_chunk(chunk.length,
-                                     self.sched.config.chunk_size)
+                b = bucket_chunk(chunk.length, self.sched.config.chunk_size)
+                with span("engine.prefill_chunk", rid=r.rid, bucket=b,
+                          pad=b - chunk.length):
                     with span("engine.inputs"):
                         ids = r.prompt[chunk.start:chunk.start
                                        + chunk.length]
@@ -144,6 +153,10 @@ class Engine:
                         lens = jnp.asarray([chunk.start], jnp.int32)
                         last = jnp.asarray([chunk.length - 1], jnp.int32)
                         row = self._row_cache(r.slot)
+                    if (self._reset_fn is not None
+                            and chunk.start == r.cache_hit_tokens):
+                        with span("engine.state_reset", slot=r.slot):
+                            row = self._reset_fn(row)
                     with span("engine.dispatch"):
                         logits, row = self._chunk_fn(b)(
                             self.params, row, toks, lens, last)
@@ -161,13 +174,16 @@ class Engine:
                     # replay mode: deterministic dummy token ids
                     # (latency-identical)
                     with span("engine.inputs"):
-                        toks = jnp.zeros((self.sched.config.max_num_seqs,),
-                                         jnp.int32)
+                        n = self.sched.config.max_num_seqs
+                        toks, active = np.zeros(n, np.int32), np.zeros(n, bool)
                         for r in plan.decodes:
-                            toks = toks.at[r.slot].set(1 + (r.generated % 7))
+                            toks[r.slot] = 1 + (r.generated % 7)
+                            active[r.slot] = True
+                        toks, active = jnp.asarray(toks), jnp.asarray(active)
                     with span("engine.dispatch"):
                         logits, self.cache = self._decode_fn(
-                            self.params, self.cache, toks, self.lengths)
+                            self.params, self.cache, toks, self.lengths,
+                            active)
                     with span("engine.sync"):
                         jax.block_until_ready(logits)
                     with span("engine.readback"):
